@@ -11,7 +11,7 @@ JAX collectives, plus one beyond-paper strategy (DESIGN.md §3):
   devices — all-gather across the chips of a card first, then across cards —
   modelling the explicit per-card partitioning of the paper.
 * ``mesh_sharded`` — paper's Mesh-Based configuration: no explicit
-  collectives; targets carry a sharded layout constraint and sources a
+  collectives; targets bind with a sharded spec and sources with a
   replicated one, and the runtime (XLA SPMD here, TT-NN there) inserts the
   communication.  "Sharded buffers for domain-decomposed data, replicated
   buffers for globally shared particle data."
@@ -40,35 +40,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: the experimental location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _smap(mesh, in_specs, out_specs, impl: str = "xla"):
-    """shard_map decorator; replication checking disabled for Pallas impls.
-
-    ``pallas_call`` has no replication rule, so running the tiled kernel
-    inside a shard needs checking off (``check_rep=False`` on jax 0.4/0.5,
-    renamed ``check_vma`` later — both are tried).  For non-Pallas impls the
-    check stays ON: it still catches mis-specified collectives at trace time.
-    """
-
-    def deco(fn):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if not str(impl).startswith("pallas"):
-            return _shard_map(fn, **kw)
-        for flag in ({"check_rep": False}, {"check_vma": False}):
-            try:
-                return _shard_map(fn, **flag, **kw)
-            except TypeError:
-                continue
-        return _shard_map(fn, **kw)
-
-    return deco
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.hermite import Evaluation, Evaluator
 from repro.kernels import nbody_force, ops
@@ -222,8 +194,8 @@ def _replicated(mesh: Mesh, order: int, kw) -> Evaluator:
     axes = mesh.axis_names
 
     @jax.jit
-    @_smap(mesh, (P(axes), P(axes), P(axes)),
-           (P(axes), P(axes), P(axes), P(axes)), kw["impl"])
+    @jax.shard_map(mesh=mesh, in_specs=(P(axes),) * 3,
+                   out_specs=(P(axes),) * 4, check_vma=False)
     def eval_padded(pos, vel, mass):
         # each device: local targets x full (gathered) source set
         with jax.named_scope("collective.all_gather"):
@@ -257,8 +229,8 @@ def _two_level(mesh: Mesh, order: int, kw) -> Evaluator:
             return jax.lax.all_gather(x, "card", axis=0, tiled=True)
 
     @jax.jit
-    @_smap(mesh, (P(axes), P(axes), P(axes)),
-           (P(axes), P(axes), P(axes), P(axes)), kw["impl"])
+    @jax.shard_map(mesh=mesh, in_specs=(P(axes),) * 3,
+                   out_specs=(P(axes),) * 4, check_vma=False)
     def eval_padded(pos, vel, mass):
         gp, gv, gm = gather2(pos), gather2(vel), gather2(mass)
         acc, jerk, pot = ops.acc_jerk_pot_rect(pos, vel, gp, gv, gm, **kw)
@@ -276,29 +248,33 @@ def _two_level(mesh: Mesh, order: int, kw) -> Evaluator:
 # Strategy 3 — mesh_sharded (Mesh-Based analogue; runtime-managed comms)
 # --------------------------------------------------------------------------
 def _mesh_sharded(mesh: Mesh, order: int, kw) -> Evaluator:
-    sharded = NamedSharding(mesh, P("dev"))          # domain-decomposed
-    sharded2 = NamedSharding(mesh, P("dev", None))
-    replicated = NamedSharding(mesh, P())            # globally shared
+    """Sharded buffers for the targets, replicated buffers for the globally
+    shared source data: the kernel regions are shard_mapped with replicated
+    in_specs for the sources, so the runtime inserts the all-gathers (cf.
+    TT-NN MeshDevice).  A Pallas kernel cannot be partitioned automatically,
+    so the regions are shard_mapped rather than left to sharding
+    constraints."""
+    sh, rep = P(mesh.axis_names), P()
+
+    @jax.shard_map(mesh=mesh, in_specs=(sh, sh, rep, rep, rep),
+                   out_specs=(sh,) * 3, check_vma=False)
+    def pass1(pt, vt, ps, vs, ms):
+        return ops.acc_jerk_pot_rect(pt, vt, ps, vs, ms, **kw)
+
+    @jax.shard_map(mesh=mesh, in_specs=(sh, sh, sh) + (rep,) * 4,
+                   out_specs=sh, check_vma=False)
+    def pass2(pt, vt, at, ps, vs, as_, ms):
+        return ops.snap_rect(pt, vt, at, ps, vs, as_, ms, **kw)
 
     @jax.jit
     def eval_padded(pos, vel, mass):
-        wsc = jax.lax.with_sharding_constraint
-        # "sharded buffers" for the targets ...
-        pt, vt = wsc(pos, sharded2), wsc(vel, sharded2)
-        # ... "replicated buffers" for the globally shared source data; the
-        # runtime inserts the all-gathers (cf. TT-NN MeshDevice).
-        with jax.named_scope("collective.replicate"):
-            ps, vs, ms = (wsc(pos, replicated), wsc(vel, replicated),
-                          wsc(mass, replicated))
-        acc, jerk, pot = ops.acc_jerk_pot_rect(pt, vt, ps, vs, ms, **kw)
-        acc = wsc(acc, sharded2)
+        # the same arrays bound twice with different specs
+        acc, jerk, pot = pass1(pos, vel, pos, vel, mass)
         if order >= 6:
-            snp = ops.snap_rect(
-                pt, vt, acc, ps, vs, wsc(acc, replicated), ms, **kw
-            )
+            snp = pass2(pos, vel, acc, pos, vel, acc, mass)
         else:
             snp = jnp.zeros_like(acc)
-        return acc, jerk, wsc(snp, sharded2), wsc(pot, sharded)
+        return acc, jerk, snp, pot
 
     return _wrap(mesh, mesh.size, order, eval_padded)
 
@@ -374,8 +350,8 @@ def _ring(mesh: Mesh, order: int, kw, ring_mode: str = "overlap") -> Evaluator:
     shift = _ring_shift(axes[0], ring)
 
     @jax.jit
-    @_smap(mesh, (P(axes), P(axes), P(axes)),
-           (P(axes), P(axes), P(axes), P(axes)), kw["impl"])
+    @jax.shard_map(mesh=mesh, in_specs=(P(axes),) * 3,
+                   out_specs=(P(axes),) * 4, check_vma=False)
     def eval_padded(pos, vel, mass):
         zeros3 = jnp.zeros_like(pos)
         zeros1 = jnp.zeros_like(mass)
@@ -697,8 +673,8 @@ def _gathered_block(mesh, order, kw, compaction, n_passes, gather):
     axes = mesh.axis_names
 
     @jax.jit
-    @_smap(mesh, (P(axes),) * 6,
-           (P(axes), P(axes), P(axes), P(axes), P(axes)), kw["impl"])
+    @jax.shard_map(mesh=mesh, in_specs=(P(axes),) * 6,
+                   out_specs=(P(axes),) * 5, check_vma=False)
     def eval_padded(pos, vel, ap, mass, mask, bound):
         src = (gather(pos), gather(vel), gather(mass))
         acc, jerk, pot, acc_s, compacted, tiles = _shard_block_body(
@@ -740,15 +716,16 @@ def _mesh_sharded_block(mesh, order, kw, compaction, n_passes):
     axes = mesh.axis_names
     sh, rep = P(axes), P()
 
-    @_smap(mesh, (sh, sh, sh, sh, sh, rep, rep, rep),
-           (sh, sh, sh, sh, sh), kw["impl"])
+    @jax.shard_map(mesh=mesh, in_specs=(sh,) * 5 + (rep,) * 3,
+                   out_specs=(sh,) * 5, check_vma=False)
     def pass1(pos, vel, ap, mask, bound, gp, gv, gm):
         acc, jerk, pot, acc_s, _, tiles = _shard_block_body(
             pos, vel, ap, mask, bound, (gp, gv, gm), kw=kw, order=order,
             compaction=compaction, n_passes=n_passes)
         return acc, jerk, pot, acc_s, tiles
 
-    @_smap(mesh, (sh, sh, sh, sh, sh, rep, rep, rep, rep), sh, kw["impl"])
+    @jax.shard_map(mesh=mesh, in_specs=(sh,) * 5 + (rep,) * 4,
+                   out_specs=sh, check_vma=False)
     def pass2(pos, vel, acc, mask, bound, gp, gv, ga, gm):
         src = (gp, gv, gm)
         n_local, n_src = pos.shape[0], gp.shape[0]
@@ -792,8 +769,8 @@ def _ring_block(mesh, order, kw, compaction, n_passes,
     shift = _ring_shift(axes[0], ring)
 
     @jax.jit
-    @_smap(mesh, (P(axes),) * 6,
-           (P(axes), P(axes), P(axes), P(axes), P(axes)), kw["impl"])
+    @jax.shard_map(mesh=mesh, in_specs=(P(axes),) * 6,
+                   out_specs=(P(axes),) * 5, check_vma=False)
     def eval_padded(pos, vel, ap, mass, mask, bound):
         n_local = pos.shape[0]
         # each of the n_passes sweeps launches once per streamed shard
@@ -819,8 +796,10 @@ def _ring_block(mesh, order, kw, compaction, n_passes,
                 return ops.acc_jerk_pot_rect(p_c, v_c, sp, sv, sm,
                                              mask_t=m_c, **kw)
 
-            zw3 = jnp.zeros((w, 3), jnp.float32)
-            zw1 = jnp.zeros((w,), jnp.float32)
+            # zeros_like keeps the accumulators varying over the ring axis,
+            # the type the loop carries them at
+            zw3 = jnp.zeros_like(window[0])
+            zw1 = jnp.zeros_like(m_w, jnp.float32)
             a_w, j_w, pt_w = _ring_sweep(
                 p, shift, ring_mode, (zw3, zw3, zw1), (pos, vel, mass),
                 lambda src: _window_switch(cap_idx, plan.caps, launch1,
@@ -928,8 +907,9 @@ def _fused_block(mesh, order, kw, compaction, n_passes):
     def vperm(mask):
         return jax.vmap(lambda mk: jnp.argsort(~mk, stable=True))(mask)
 
-    @_smap(mesh, (tsh3, tsh3, tsh3, tsh2, tsh2, ssh3, ssh3, ssh2),
-           (tsh3, tsh3, tsh2, tsh3, tsh2), kw["impl"])
+    @jax.shard_map(mesh=mesh,
+                   in_specs=(tsh3, tsh3, tsh3, tsh2, tsh2, ssh3, ssh3, ssh2),
+                   out_specs=(tsh3, tsh3, tsh2, tsh3, tsh2), check_vma=False)
     def pass1(pos, vel, ap, mask, bound, gp, gv, gm):
         b_loc = pos.shape[0]
         plan = _shard_plan(pos.shape[1], gp.shape[1], kw, n_passes)
@@ -950,8 +930,10 @@ def _fused_block(mesh, order, kw, compaction, n_passes):
             tiles = jnp.full((b_loc, 1), plan.dense_tiles, jnp.int32)
         return acc, jerk, pot, acc_s, tiles
 
-    @_smap(mesh, (tsh3, tsh3, tsh3, tsh2, tsh2, ssh3, ssh3, ssh3, ssh2),
-           tsh3, kw["impl"])
+    @jax.shard_map(mesh=mesh,
+                   in_specs=(tsh3, tsh3, tsh3, tsh2, tsh2, ssh3, ssh3, ssh3,
+                             ssh2),
+                   out_specs=tsh3, check_vma=False)
     def pass2(pos, vel, acc, mask, bound, gp, gv, ga, gm):
         plan = _shard_plan(pos.shape[1], gp.shape[1], kw, n_passes)
         if compaction == "gather":

@@ -250,14 +250,25 @@ def grid_tiles(n_t: int, n_s: int, block_i: int, block_j: int) -> int:
     return -(-n_t // block_i) * -(-n_s // block_j)
 
 
+# Block index maps.  The constant block index is an explicit int32: under
+# x64 a bare ``0`` lowers as an i64 literal next to the i32 grid index, and
+# Mosaic refuses the mixed ``(i32, i64)`` return.
+def _row_block(i, j):
+    return i, jnp.int32(0)
+
+
+def _col_block(i, j):
+    return jnp.int32(0), j
+
+
 def _grid_specs(n_t: int, n_s: int, block_i: int, block_j: int):
     # n_t is independent of n_s (rectangular contract): the compaction layer
     # exploits exactly this by shrinking the target extent to the gathered
     # active block while sources stay full.
     grid = (n_t // block_i, n_s // block_j)
-    tgt_spec = pl.BlockSpec((block_i, 8), lambda i, j: (i, 0))
-    src_spec = pl.BlockSpec((8, block_j), lambda i, j: (0, j))
-    out_spec = pl.BlockSpec((block_i, 8), lambda i, j: (i, 0))
+    tgt_spec = pl.BlockSpec((block_i, 8), _row_block)
+    src_spec = pl.BlockSpec((8, block_j), _col_block)
+    out_spec = pl.BlockSpec((block_i, 8), _row_block)
     return grid, tgt_spec, src_spec, out_spec
 
 
@@ -333,8 +344,8 @@ def snap_packed(
     """Pallas all-pairs snap pass on packed operands (see module docstring)."""
     n_t, n_s = tgt.shape[0], src.shape[1]
     grid, tgt_spec, src_spec, out_spec = _grid_specs(n_t, n_s, block_i, block_j)
-    acc_t_spec = pl.BlockSpec((block_i, 8), lambda i, j: (i, 0))
-    acc_s_spec = pl.BlockSpec((8, block_j), lambda i, j: (0, j))
+    acc_t_spec = pl.BlockSpec((block_i, 8), _row_block)
+    acc_s_spec = pl.BlockSpec((8, block_j), _col_block)
     out_specs, out_shape, unpack = _out_wiring(n_t, out_spec, compute_dtype)
     return unpack(pl.pallas_call(
         functools.partial(_snap_kernel, eps=eps,

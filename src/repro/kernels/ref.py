@@ -51,7 +51,9 @@ def compensated_sum(x, axis: int = 0):
         err = jnp.where(jnp.abs(s) >= jnp.abs(v), (s - t) + v, (v - t) + s)
         return (t, c + err), None
 
-    zero = jnp.zeros(x.shape[1:], x.dtype)
+    # zeros_like(x[0]) carries x's varying manual axes inside a shard_map,
+    # so the scan carry keeps one type across iterations
+    zero = jnp.zeros_like(x[0])
     (s, c), _ = jax.lax.scan(add, (zero, zero), x)
     return s + c
 

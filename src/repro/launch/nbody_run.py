@@ -7,17 +7,18 @@ under any of the paper's three scaling strategies (+ the beyond-paper ring):
   PYTHONPATH=src python -m repro.launch.nbody_run --n 4096 --t-end 1.0 \
       --strategy replicated --devices 4
 
-``--devices k`` (k > 1) needs host-platform placeholder devices; the launcher
-sets XLA_FLAGS accordingly BEFORE importing jax, mirroring the paper's tt-run
-process-per-card launch.
+``--devices k`` takes the first k visible devices and fails when fewer are
+visible; on the CPU (``JAX_PLATFORMS=cpu``) the launcher forces k host
+devices before importing jax.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
+
+from repro.launch.sim_run import force_host_devices
 
 
 def main():
@@ -38,14 +39,15 @@ def main():
     ap.add_argument("--x64", action="store_true", default=True)
     args = ap.parse_args()
 
-    if args.devices > 1 and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+    force_host_devices(args.devices)
 
     import jax
 
     if args.x64:
         jax.config.update("jax_enable_x64", True)
+    if args.devices > len(jax.devices()):
+        raise SystemExit(f"--devices {args.devices}: only "
+                         f"{len(jax.devices())} visible")
 
     from repro.core import hermite, nbody
     from repro.core.evaluate import make_evaluator

@@ -39,9 +39,11 @@ Each invocation emits a one-line summary plus a JSON telemetry report
 (wall time, steps/s, interactions/s, modeled energy/EDP, per-run energy
 conservation) under ``experiments/sim/`` (override with ``--out``).
 
-``--devices k`` (k > 1) needs host-platform placeholder devices; the
-launcher sets XLA_FLAGS accordingly BEFORE importing jax, mirroring the
-paper's tt-run process-per-card launch.
+``--devices k`` takes the first k visible devices and fails when fewer are
+visible.  On the CPU (``JAX_PLATFORMS=cpu``) the launcher forces k host
+devices through XLA_FLAGS before importing jax; on a TPU host ``--devices 4``
+is the four chips.  The persistent compilation cache lives where
+``repro.launch.compile_cache`` says.
 """
 
 from __future__ import annotations
@@ -49,6 +51,15 @@ from __future__ import annotations
 import argparse
 import os
 import sys
+
+
+def force_host_devices(n: int) -> None:
+    """Give the CPU backend ``n`` devices, only when the user asked for the
+    CPU (``JAX_PLATFORMS=cpu``) and set no XLA_FLAGS of their own; must run
+    before jax is imported.  On an accelerator the real devices are used."""
+    if (n > 1 and os.environ.get("JAX_PLATFORMS") == "cpu"
+            and "XLA_FLAGS" not in os.environ):
+        os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
 
 
 def _parse_params(pairs):
@@ -79,7 +90,7 @@ def main(argv=None):
                          " or an integer N_max")
     ap.add_argument("--kernel", default=None, choices=(None, "ref", "pallas"),
                     help="force kernel: 'ref' (all-pairs XLA op) or 'pallas' "
-                         "(tiled kernel; interpret mode off-TPU)")
+                         "(tiled kernel; interpret mode on the CPU)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ensemble", type=int, default=1,
                     help="batch B independent runs (seeds seed..seed+B-1)")
@@ -178,15 +189,16 @@ def main(argv=None):
     ap.add_argument("--list-scenarios", action="store_true")
     args = ap.parse_args(argv)
 
-    if args.devices > 1 and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+    force_host_devices(args.devices)
 
     import jax
 
     jax.config.update("jax_enable_x64", True)
 
+    from repro.launch import compile_cache
     from repro.sim import api, scenarios, telemetry
+
+    compile_cache.enable()
 
     if args.list_scenarios:
         for name in scenarios.available():

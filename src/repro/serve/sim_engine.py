@@ -312,7 +312,8 @@ class Pod:
                 dt_max=cfg.dt_max, n_levels=cfg.n_levels)
         recorder = telemetry.TelemetryRecorder({
             **req.describe(), "request_id": pending.request_id,
-            "n": req.spec.n, "pod_cap": self.cap, "dtype": cfg.dtype})
+            "n": req.spec.n, "pod_cap": self.cap, "dtype": cfg.dtype,
+            "impl": cfg.impl})
         recorder.record_snapshot(0, 0.0, energy=e0, de_rel=0.0)
         s = _Slot(request_id=pending.request_id, request=req,
                   t_submit=pending.t_submit, t_admit=now, e0=e0,
@@ -604,6 +605,8 @@ class SimServer:
                 pod.admit(warm, slot, self._now())     # (1, cap) admission
                 pod.advance()                          # (B, cap) engines
                 pod.retire(slot, self._now())          # diagnostics + report
+                pod.admit(warm, slot, self._now())     # backfill into a carry
+                pod.retire(slot, self._now())
                 pod.t_end[slot] = 0.0                  # freeze the warm rows
         return self.cache_misses() - before
 

@@ -256,9 +256,11 @@ def _device_list(cfg: SimConfig):
     devs = jax.devices()
     if cfg.devices > len(devs):
         raise ValueError(
-            f"requested {cfg.devices} devices, only {len(devs)} visible "
-            "(set XLA_FLAGS=--xla_force_host_platform_device_count before "
-            "importing jax — the sim_run CLI does this)")
+            f"requested {cfg.devices} devices, only {len(devs)} "
+            f"{devs[0].platform} device(s) visible (on the CPU, force host "
+            "devices with XLA_FLAGS=--xla_force_host_platform_device_count "
+            "before importing jax; the sim_run CLI does this under "
+            "JAX_PLATFORMS=cpu)")
     return devs[: cfg.devices]
 
 
@@ -420,9 +422,11 @@ class SingleRunner(Runner):
         if cfg.strategy == "single":
             if impl == "fp64" or cfg.dtype == "fp64":
                 # golden reference: a precision, not a kernel
+                impl = "fp64"
                 evaluator = make_evaluator(precision="fp64", order=cfg.order,
                                            eps=cfg.eps)
             else:
+                impl = impl or ops.default_impl()
                 evaluator = make_evaluator(order=cfg.order, eps=cfg.eps,
                                            impl=impl, dtype=cfg.dtype)
         elif cfg.strategy in STRATEGIES:
@@ -430,13 +434,14 @@ class SingleRunner(Runner):
                 raise ValueError(
                     "fp64 (golden reference) only runs under "
                     "strategy='single'")
+            impl = impl or "xla"
             evaluator = make_strategy_evaluator(
                 cfg.strategy, devices=_device_list(cfg), order=cfg.order,
-                eps=cfg.eps, impl=impl or "xla", dtype=cfg.dtype)
+                eps=cfg.eps, impl=impl, dtype=cfg.dtype)
         else:
             raise ValueError(f"unknown strategy {cfg.strategy!r}")
 
-        h.recorder = telemetry.TelemetryRecorder(cfg.meta())
+        h.recorder = telemetry.TelemetryRecorder({**cfg.meta(), "impl": impl})
         state = hermite.initialize(state, evaluator)
         jax.block_until_ready(state.pos)
         h.e0 = float(nbody.total_energy(state))
@@ -531,7 +536,7 @@ class BlockStrategyRunner(Runner):
             block_i=cfg.block_i or nbody_force.DEFAULT_BLOCK_I,
             block_j=cfg.block_j or nbody_force.DEFAULT_BLOCK_J)
 
-        h.recorder = telemetry.TelemetryRecorder(cfg.meta())
+        h.recorder = telemetry.TelemetryRecorder({**cfg.meta(), "impl": impl})
         state = hermite.initialize(state, evaluator)
         jax.block_until_ready(state.pos)
         h.e0 = float(nbody.total_energy(state))
@@ -656,7 +661,7 @@ class EnsembleRunner(Runner):
         h.n_max = batched.pos.shape[1]
         h.n_active, h.runs_meta = n_active, runs_meta
 
-        h.recorder = telemetry.TelemetryRecorder(cfg.meta())
+        h.recorder = telemetry.TelemetryRecorder({**cfg.meta(), "impl": impl})
         reg = obs_metrics.registry()
         reg.gauge("sim.pad_waste", unit="fraction",
                   help="zero-mass padded slot fraction of the batch").set(

@@ -204,6 +204,20 @@ def _constrain(tree, mesh):
     return jax.tree_util.tree_map(one, tree)
 
 
+def _batch_map(fn, mesh, in_axes=0):
+    """``vmap(fn, in_axes)`` over the batch axis; on a mesh, inside a
+    ``shard_map`` over that axis, so each device runs its own members
+    (replicated over the ``"dev"`` axis of a fused mesh).  A Pallas kernel
+    cannot be partitioned through sharding constraints."""
+    fn = jax.vmap(fn, in_axes=in_axes)
+    if mesh is None:
+        return fn
+    specs = tuple(P(BATCH_AXIS) if a == 0 else P() for a in in_axes) \
+        if isinstance(in_axes, tuple) else P(BATCH_AXIS)
+    return jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                         out_specs=P(BATCH_AXIS), check_vma=False)
+
+
 def _count_engine_build(kind: str) -> None:
     """Emit one ``engine.cache_miss`` tick into the current metrics registry.
 
@@ -223,13 +237,17 @@ def _engine(order: int, eps: float, impl: str, mesh, dtype: str):
     _count_engine_build("fixed")
     ev = _inner_evaluator(order, eps, impl, dtype)
 
+    init_all = _batch_map(
+        lambda s, na: hermite.initialize(s, _mask_evaluator(ev, na)), mesh)
+    step_all = _batch_map(
+        lambda m, na, dt: hermite.step(m, dt.astype(m.dtype),
+                                       _mask_evaluator(ev, na), order=order),
+        mesh, in_axes=(0, 0, None))
+
     @jax.jit
     def init(batched: ParticleState, n_active) -> ParticleState:
         batched, n_active = _constrain((batched, n_active), mesh)
-        out = jax.vmap(
-            lambda s, na: hermite.initialize(s, _mask_evaluator(ev, na))
-        )(batched, n_active)
-        return _constrain(out, mesh)
+        return _constrain(init_all(batched, n_active), mesh)
 
     @functools.partial(jax.jit, static_argnames=("n_steps",))
     def run(batched: ParticleState, n_active, dt, n_steps: int
@@ -237,12 +255,7 @@ def _engine(order: int, eps: float, impl: str, mesh, dtype: str):
         batched, n_active = _constrain((batched, n_active), mesh)
 
         def body(s, _):
-            s1 = jax.vmap(
-                lambda m, na: hermite.step(m, dt.astype(m.dtype),
-                                           _mask_evaluator(ev, na),
-                                           order=order)
-            )(s, n_active)
-            return _constrain(s1, mesh), None
+            return _constrain(step_all(s, n_active, dt), mesh), None
 
         out, _ = jax.lax.scan(body, batched, None, length=n_steps)
         return out
@@ -406,14 +419,15 @@ def _adaptive_engine(order: int, eps: float, impl: str, mesh,
             lambda new, old: jnp.where(active, new, old), s1, s)
         return s1, jnp.where(active, h, hp), active
 
+    step_all = _batch_map(one_step, mesh)
+
     @functools.partial(jax.jit, static_argnames=("n_steps",))
     def run(batched, h_prev, n_taken, n_active, t_end, n_steps: int):
         batched, n_active = _constrain((batched, n_active), mesh)
 
         def body(carry, _):
             s, hp, cnt = carry
-            s1, hp1, active = jax.vmap(one_step, in_axes=(0, 0, 0, 0))(
-                s, hp, n_active, t_end)
+            s1, hp1, active = step_all(s, hp, n_active, t_end)
             return (_constrain(s1, mesh), hp1,
                     cnt + active.astype(cnt.dtype)), None
 

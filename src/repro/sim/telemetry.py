@@ -192,6 +192,7 @@ class TelemetryRecorder:
             "n_bodies": n_bodies,
             "ensemble": ensemble,
             "devices": n_devices,
+            "device": device_info(n_devices),
             **({"n_active": [int(a) for a in n_active]}
                if n_active is not None else {}),
             **({"force_evals": force_evals,
@@ -224,6 +225,16 @@ class TelemetryRecorder:
         if extra:
             report.update(extra)
         return RunReport(report)
+
+
+def device_info(count: int) -> Dict[str, Any]:
+    """The devices a run used, as JAX reports them: a run that fell back to
+    the CPU says so in its report."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": count}
 
 
 def write_report(report: Dict[str, Any], path: str) -> str:

@@ -122,6 +122,31 @@ def test_zero_cache_miss_after_warmup():
     assert server.cache_misses() == baseline
 
 
+def test_zero_xla_compiles_after_warmup():
+    """Warm-up also covers the eager ops of admission, backfill into a
+    running pod's carry, and retirement: serving compiles nothing."""
+    compiles = []
+    counting = False
+
+    def listen(event, secs, **_):
+        if counting and event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    server = SimServer(_cfg())
+    server.warmup([_req("plummer:24", "adaptive"),
+                   _req("plummer:40", "block")])
+    counting = True
+    try:
+        for seed in range(3):
+            server.submit(_req("plummer:24", "adaptive", 0.02, seed=seed))
+            server.submit(_req("plummer:40", "block", 0.02, seed=seed))
+        assert len(server.run_until_drained()) == 6
+    finally:
+        counting = False
+    assert compiles == []
+
+
 # --------------------------------------------------------------------------
 # retirement reports
 # --------------------------------------------------------------------------
