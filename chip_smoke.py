@@ -21,7 +21,7 @@ One chip, three phases in one process, all on the compiled Pallas kernels:
 runs (``BlockStrategyRunner``), a few fixed-dt lockstep steps of each strategy
 (``SingleRunner``) and the fused ``mesh=(2, 2)`` ensemble of 2 members, each
 against the same seed on one chip in this process, on the forces at t = 0 and
-on |dE/E|.
+on |dE/E| (within ``DE_MATCH`` of the one-chip value).
 
 Each phase prints one line of its numbers.  The last line of standard output
 is ``{"ok": true, "device": {...}}``, printed only when every check passed on
@@ -62,6 +62,13 @@ FOUR_CHIP_LEVELS = 9
 SERVER_N = (4096, 4096, 8192, 8192)
 LOCKSTEP_DT, LOCKSTEP_STEPS = 1.0 / 1024, 3
 FUSED_N = 16384
+#: a four-chip run must end at the one-chip |dE/E| within this.  The layouts
+#: differ only in the order of the fp32 force sums (bitwise equal at t = 0
+#: except ring, 6e-7 normwise), which moves |dE/E| over a few hundred events
+#: by far less than 1e-6; the runs measured so far agree to every printed
+#: digit.  A shard that lost or double counted sources errs by O(1) in its
+#: forces and by orders of magnitude more than 1e-6 in |dE/E|.
+DE_MATCH = 1e-6
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
@@ -318,12 +325,14 @@ def four_chip_phase(jax, impl, kind):
         (f1, r1), t0 = ref, time.perf_counter()
         f4, r4, in_use = drive(cfg4)
         err = {k: normwise(f4[k], f1[k]) for k in TOL}
+        de_diff = abs(r4["de_rel"] - r1["de_rel"])
         say(label, impl=r4["impl"], device_kind=kind,
             devices=r4["device"]["count"],
             wall_s=f"{time.perf_counter() - t0:.3f}", events=r4["steps"],
             grid_tiles_total=r4.get("grid_tiles_total", "n/a"),
             grid_tiles_per_shard=r4.get("grid_tiles_per_shard", "n/a"),
             de_rel=f"{r4['de_rel']:.3e}", de_rel_1chip=f"{r1['de_rel']:.3e}",
+            de_rel_diff=f"{de_diff:.3e}(tol {DE_MATCH:.0e})",
             bytes_in_use=",".join(map(str, in_use)),
             **{f"t0_diff_{k}": f"{v:.3e}" for k, v in err.items()})
         check_report(r4, impl, label)
@@ -332,6 +341,9 @@ def four_chip_phase(jax, impl, kind):
                 f"{label}: a device held no memory ({in_use})")
         for k, v in err.items():
             require(v <= TOL[k], f"{label}: t=0 {k} differs by {v:.3e}")
+        require(de_diff <= DE_MATCH,
+                f"{label}: |dE/E| {r4['de_rel']:.3e} vs {r1['de_rel']:.3e} "
+                f"on one chip")
 
     block = dict(scenario="binary_plummer", n=N, seed=SEED, t_end=DT_MAX,
                  dt_max=DT_MAX, n_levels=FOUR_CHIP_LEVELS, stepper="block",

@@ -208,14 +208,29 @@ def _batch_map(fn, mesh, in_axes=0):
     """``vmap(fn, in_axes)`` over the batch axis; on a mesh, inside a
     ``shard_map`` over that axis, so each device runs its own members
     (replicated over the ``"dev"`` axis of a fused mesh).  A Pallas kernel
-    cannot be partitioned through sharding constraints."""
+    cannot be partitioned through sharding constraints.  A batch that does
+    not divide over the mesh (one bucket group of the block engine) is
+    padded by repeating its first member, and the padding is dropped."""
     fn = jax.vmap(fn, in_axes=in_axes)
     if mesh is None:
         return fn
-    specs = tuple(P(BATCH_AXIS) if a == 0 else P() for a in in_axes) \
-        if isinstance(in_axes, tuple) else P(BATCH_AXIS)
-    return jax.shard_map(fn, mesh=mesh, in_specs=specs,
-                         out_specs=P(BATCH_AXIS), check_vma=False)
+    axes = in_axes if isinstance(in_axes, tuple) else None
+    specs = tuple(P(BATCH_AXIS) if a == 0 else P() for a in axes) \
+        if axes else P(BATCH_AXIS)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                            out_specs=P(BATCH_AXIS), check_vma=False)
+    extent = mesh.shape[BATCH_AXIS]
+
+    def call(*args):
+        mapped = [axes is None or axes[i] == 0 for i in range(len(args))]
+        b = jax.tree_util.tree_leaves(args[mapped.index(True)])[0].shape[0]
+        if b % extent == 0:
+            return sharded(*args)
+        args = tuple(_pad_batch(a, extent)[0] if m else a
+                     for a, m in zip(args, mapped))
+        return jax.tree_util.tree_map(lambda x: x[:b], sharded(*args))
+
+    return call
 
 
 def _count_engine_build(kind: str) -> None:
@@ -857,8 +872,8 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                     with jax.named_scope(f"event.bucket_switch.g{gi}"):
                         cap_idx = shared_cap_index(gplan, jnp.where(
                             live[members], n_act[members], 0))
-                        evs.append(jax.vmap(
-                            gbev, in_axes=(0, 0, 0, 0, 0, 0, None))(
+                        evs.append(_batch_map(
+                            gbev, mesh, in_axes=(0, 0, 0, 0, 0, 0, None))(
                                 xp[members], vp[members], ap[members],
                                 s.mass[members], active[members],
                                 perm[members], cap_idx))
@@ -875,7 +890,7 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                 hits_event = jnp.concatenate(hits_parts)[inv]
             else:
                 with jax.named_scope("event.force"):
-                    ev = jax.vmap(bev)(xp, vp, ap, s.mass, active)
+                    ev = _batch_map(bev, mesh)(xp, vp, ap, s.mass, active)
                 tiles_event = jnp.asarray(full_tiles, count_dtype)
             with jax.named_scope("event.post"):
                 s1, t_last, levels, dt_macro, dp, live = jax.vmap(
@@ -928,8 +943,8 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                     Evaluation; members whose result the caller discards
                     (refreshing ones) just ride the vmap.
                     """
-                    a_n, j_n, p_n = jax.vmap(
-                        near1, in_axes=(0, 0, 0, 0, 0, 0, None))(
+                    a_n, j_n, p_n = _batch_map(
+                        near1, mesh, in_axes=(0, 0, 0, 0, 0, 0, None))(
                             xp, vp, s.mass, mask, win_idx, win_cnt, w_idx)
                     hf = ((t_next - jnp.maximum(nb.t_ref, 0))
                           .astype(state_dtype) * c.dt_macro / n_sub)
@@ -939,8 +954,9 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                     acc_t = a_n.astype(state_dtype) + a_far
                     if order >= 6:
                         acc_s = jnp.where(mask[..., None], acc_t, ap)
-                        s_n = jax.vmap(
-                            near2, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None))(
+                        s_n = _batch_map(
+                            near2, mesh,
+                            in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None))(
                                 xp, vp, acc_t, acc_s, s.mass, mask,
                                 win_idx, win_cnt, w_idx)
                         snp = s_n.astype(state_dtype) + nb.snap_far
@@ -992,7 +1008,8 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                     # positions; new windows from the same positions; far =
                     # full - near with IDENTICAL acc operands in both
                     with jax.named_scope("event.neighbor_refresh"):
-                        ev_f = jax.vmap(bev)(xp, vp, ap, s.mass, real)
+                        ev_f = _batch_map(bev, mesh)(xp, vp, ap, s.mass,
+                                                     real)
                     win_idx_n, win_cnt_n = jax.vmap(
                         lambda p_, v_: neighbor.build_windows(
                             p_, v_, block_i=block_i, block_j=block_j,
@@ -1000,8 +1017,8 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                     wmax_n = jnp.max(jnp.where(need[:, None],
                                                win_cnt_n, 0))
                     w_new = nplan.source_bucket(wmax_n * block_j)
-                    a_nn, j_nn, p_nn = jax.vmap(
-                        near1, in_axes=(0, 0, 0, 0, 0, 0, None))(
+                    a_nn, j_nn, p_nn = _batch_map(
+                        near1, mesh, in_axes=(0, 0, 0, 0, 0, 0, None))(
                             xp, vp, s.mass, real, win_idx_n, win_cnt_n,
                             w_new)
                     af = ev_f.acc.astype(state_dtype)
@@ -1009,8 +1026,9 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                     pf = ev_f.pot.astype(state_dtype)
                     if order >= 6:
                         acc_s = jnp.where(real[..., None], af, ap)
-                        s_nn = jax.vmap(
-                            near2, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None))(
+                        s_nn = _batch_map(
+                            near2, mesh,
+                            in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None))(
                                 xp, vp, af, acc_s, s.mass, real,
                                 win_idx_n, win_cnt_n, w_new)
                         sf = ev_f.snap.astype(state_dtype)
@@ -1301,9 +1319,11 @@ def ensemble_run_block(
     and shared per shard (``bucket_mode`` does not apply — the switch lives
     inside the shards).  With ``sources="neighbor"`` the vmapped engine
     keeps running and the 2-D mesh rides as a sharding *constraint* on the
-    ``(B, N)`` state leaves — GSPMD partitions each member's windowed
-    kernels along ``dev``, which is what lets several large-N
-    neighbor-scheme members share one device group's memory.  ``mesh=None``
+    ``(B, N)`` state leaves between events, which is what lets several
+    large-N neighbor-scheme members share one device group's memory; the
+    windowed kernels run per batch shard inside a ``shard_map``, each
+    member gathered whole along ``dev`` (a Pallas kernel cannot be
+    partitioned by sharding constraints).  ``mesh=None``
     (default) is the 1-D batch-sharded layout, unchanged.
     """
     if n_levels < 1:

@@ -10,6 +10,7 @@ process at a time may load the TPU library, and it keeps it until it exits,
 so the tests compile in this process and live in this one file.
 """
 
+import importlib.util
 import os
 
 import jax
@@ -31,13 +32,12 @@ F32 = jnp.float32
 
 @pytest.fixture(scope="module")
 def topo():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("the TPU compiler (libtpu) is not installed")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler in this installation
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
 
 
 @pytest.fixture(scope="module")
@@ -142,21 +142,29 @@ def test_batch_sharded_engines_compile_on_four_chips(
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-def test_block_engine_gather_compiles(one_chip, no_persistent_cache):
+@pytest.mark.parametrize("layout", ["one_chip", "batch"])
+def test_block_engine_gather_compiles(topo, no_persistent_cache, layout):
     """The main path's event-loop program: block timesteps with the
-    gathered-compaction capacity switch over the kernel, at N = 16384."""
+    gathered-compaction capacity switch over the kernel, at N = 16384, on
+    one chip or with the batch sharded over the four described chips."""
     n = 16384
     bi, bj = nbody_force.DEFAULT_BLOCK_I, nbody_force.DEFAULT_BLOCK_J
-    groups = ens._bucket_groups(n, np.array([n]), bi, bj, "gather", "member")
-    init, run = ens._block_engine(6, 1e-7, "pallas", None, 0.02, 0.0625, 8,
+    if layout == "one_chip":
+        mesh, b = None, 1
+        sharding = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh, b = ens._batch_mesh(topo.devices), 4
+        sharding = NamedSharding(mesh, P())
+    groups = ens._bucket_groups(n, np.full(b, n), bi, bj, "gather", "member")
+    init, run = ens._block_engine(6, 1e-7, "pallas", mesh, 0.02, 0.0625, 8,
                                   "gather", bi, bj, groups, "fp32")
     batched = jax.tree_util.tree_map(
-        lambda x: _sds((1,) + x.shape, x.dtype, one_chip),
-        _state_shapes(n, one_chip))
-    na = _sds((1,), jnp.int32, one_chip)
-    t_end = _sds((1,), jnp.float64, one_chip)
+        lambda x: _sds((b,) + x.shape, x.dtype, sharding),
+        _state_shapes(n, sharding))
+    na = _sds((b,), jnp.int32, sharding)
+    t_end = _sds((b,), jnp.float64, sharding)
     carry = jax.tree_util.tree_map(
-        lambda x: _sds(x.shape, x.dtype, one_chip),
+        lambda x: _sds(x.shape, x.dtype, sharding),
         jax.eval_shape(init, batched, na, t_end))
     text = run.lower(batched, carry, na, t_end, n_events=16).compile() \
         .as_text()
