@@ -52,8 +52,9 @@ def shared_cap_index(plan: ops.CapacityPlan, bounds) -> jax.Array:
     rows that include padding) lands on the full-window bucket instead of
     out of range.
     """
-    bound = jnp.max(jnp.asarray(bounds, jnp.int32).reshape(-1))
-    return plan.bucket(jnp.minimum(bound, plan.caps[-1]))
+    with jax.named_scope("compaction.bucket_index"):
+        bound = jnp.max(jnp.asarray(bounds, jnp.int32).reshape(-1))
+        return plan.bucket(jnp.minimum(bound, plan.caps[-1]))
 
 
 def _rect_passes(*, eps, impl, block_i, block_j, precision, dtype):
@@ -128,8 +129,8 @@ def make_block_evaluator(
       masked launch (inactive i-blocks are ``pl.when``-skipped but their
       tiles are still enqueued).
     * ``"gather"`` — ``evaluate(pos, vel, acc_pred, mass, mask_t, perm,
-      cap_idx)``: ``perm`` orders active targets first (``jnp.argsort`` of
-      the negated mask), ``cap_idx`` selects the static capacity bucket
+      cap_idx)``: ``perm`` orders active targets first
+      (``ops.active_first``), ``cap_idx`` selects the static capacity bucket
       (``ops.capacity_buckets(n, block_i)``) — it must bound the true active
       count, and must be unbatched under ``vmap``.  Output is bit-for-bit
       the ``"none"`` result: each target row is a row-local reduction over
@@ -164,10 +165,12 @@ def make_block_evaluator(
     if compaction == "none":
 
         def evaluate(pos, vel, acc_pred, mass, mask_t) -> Evaluation:
-            p, v, m = cast(pos), cast(vel), cast(mass)
+            with jax.named_scope(ops.PACK):
+                p, v, m = cast(pos), cast(vel), cast(mass)
             acc, jerk, pot = rect1(p, v, p, v, m, mask_t)
             if order >= 6:
-                acc_s = jnp.where(mask_t[:, None], acc, cast(acc_pred))
+                with jax.named_scope("compaction.scatter_sources"):
+                    acc_s = jnp.where(mask_t[:, None], acc, cast(acc_pred))
                 snp = rect2(p, v, acc, p, v, acc_s, m, mask_t)
             else:
                 snp = jnp.zeros_like(acc)
@@ -181,7 +184,8 @@ def make_block_evaluator(
         caps = ops.capacity_buckets(n, block_i)
         if n_caps is not None:
             caps = caps[: min(n_caps, len(caps))]
-        p, v, m, ap = cast(pos), cast(vel), cast(mass), cast(acc_pred)
+        with jax.named_scope(ops.PACK):
+            p, v, m, ap = cast(pos), cast(vel), cast(mass), cast(acc_pred)
 
         def make_branch(cap: int):
             def branch(p, v, ap, m, mask_t, perm) -> Evaluation:
@@ -204,8 +208,10 @@ def make_block_evaluator(
 
             return branch
 
-        return jax.lax.switch(cap_idx, [make_branch(c) for c in caps],
-                              p, v, ap, m, mask_t, perm)
+        # the switch's index clamp belongs to the bucket choice
+        with jax.named_scope("compaction.bucket_index"):
+            return jax.lax.switch(cap_idx, [make_branch(c) for c in caps],
+                                  p, v, ap, m, mask_t, perm)
 
     return evaluate_gather
 

@@ -513,7 +513,8 @@ def _shard_bucket(plan, bound):
     """Bucket index from the shard's ``(1,)`` active-count bound (clamped
     to the local extent, so an over-wide analytic bound still lands on the
     full-window bucket instead of out of range)."""
-    return plan.bucket(jnp.minimum(bound[0], plan.caps[-1]))
+    with jax.named_scope("compaction.bucket_index"):
+        return plan.bucket(jnp.minimum(bound[0], plan.caps[-1]))
 
 
 def _shard_block_body(pos, vel, ap, mask, bound, src, *, kw, order,
@@ -532,7 +533,7 @@ def _shard_block_body(pos, vel, ap, mask, bound, src, *, kw, order,
     n_local, n_src = pos.shape[0], src[0].shape[0]
     plan = _shard_plan(n_local, n_src, kw, n_passes)
     if compaction == "gather":
-        perm = jnp.argsort(~mask, stable=True)
+        perm = ops.active_first(mask)
         cap_idx = _shard_bucket(plan, bound)
         acc, jerk, pot, acc_s = _shard_pass1(pos, vel, ap, mask, perm,
                                              cap_idx, plan, kw, src, order)
@@ -732,7 +733,7 @@ def _mesh_sharded_block(mesh, order, kw, compaction, n_passes):
         plan = _shard_plan(n_local, n_src, kw, n_passes)
         if compaction == "gather":
             # same bucket as pass 1: the local active set did not change
-            perm = jnp.argsort(~mask, stable=True)
+            perm = ops.active_first(mask)
             cap_idx = _shard_bucket(plan, bound)
             return _shard_pass2(pos, vel, acc, mask, perm, cap_idx, plan,
                                 kw, src, ga)
@@ -783,7 +784,7 @@ def _ring_block(mesh, order, kw, compaction, n_passes,
             # stream rotates sources, the compacted target block is fixed,
             # and partial sums accumulate in the window layout (same adds,
             # one scatter at the end)
-            perm = jnp.argsort(~mask, stable=True)
+            perm = ops.active_first(mask)
             cap_idx = _shard_bucket(plan, bound)
             tiles = jnp.reshape(plan.tiles(cap_idx), (1,))
             cap_max = plan.caps[-1]
@@ -905,7 +906,7 @@ def _fused_block(mesh, order, kw, compaction, n_passes):
     ssh3, ssh2 = P("batch", None, None), P("batch", None)
 
     def vperm(mask):
-        return jax.vmap(lambda mk: jnp.argsort(~mk, stable=True))(mask)
+        return jax.vmap(ops.active_first)(mask)
 
     @jax.shard_map(mesh=mesh,
                    in_specs=(tsh3, tsh3, tsh3, tsh2, tsh2, ssh3, ssh3, ssh2),

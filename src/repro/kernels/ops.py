@@ -34,6 +34,13 @@ stay full, and the Pallas kernel skips fully-inactive i-blocks via
 array arguments, and ``pallas_call`` batches by prepending a grid dimension,
 so ``jax.vmap`` lifts both the XLA fallback and the Pallas kernel (compiled
 or interpreted) over a leading batch axis — the ensemble engine's path.
+
+**Layer scopes**: the work around the kernels carries ``compaction.*``
+names in the HLO's ``op_name`` metadata — ``compaction.perm``,
+``compaction.gather.cap<c>``, ``compaction.scatter.cap<c>``,
+``compaction.scatter_sources.cap<c>`` and ``compaction.pack`` for the
+``(N, 8)`` / ``(8, N)`` operand repacking — while the kernels themselves
+are known by their Pallas names (``acc_jerk_pot_packed``, ``snap_packed``).
 """
 
 from __future__ import annotations
@@ -48,6 +55,8 @@ import jax.numpy as jnp
 from repro.kernels import nbody_force, ref
 
 _PAD_COLS = 8
+#: the named scope of the operand repacking around the Pallas kernels
+PACK = "compaction.pack"
 IMPLS = ("pallas", "pallas_interpret", "xla", "pallas_marked")
 # pallas_marked: ref math inside a PALLAS_VMEM_REGION named scope — the
 # dry-run cost model for the deployed Pallas kernel (Mosaic cannot lower on
@@ -378,15 +387,23 @@ class CapacityPlan:
         return self.restrict(n_active).caps[-1]
 
 
+def active_first(mask):
+    """The permutation that puts the active rows first (a stable argsort of
+    the negated mask; row order inside the gathered buffer is irrelevant to
+    the row-local kernel math, the permutation only densifies the launch)."""
+    with jax.named_scope("compaction.perm"):
+        return jnp.argsort(~mask, stable=True)
+
+
 def compact_targets(perm, cap: int, *rows):
     """Gather the first ``cap`` permuted rows of each per-target array.
 
-    ``perm`` puts active rows first (e.g. ``jnp.argsort(~mask)``), so with
+    ``perm`` puts active rows first (:func:`active_first`), so with
     ``cap >= n_active`` the gathered buffer holds every active target
     followed by inactive fill rows (whose outputs the activity mask zeroes).
     ``cap`` is static — each capacity bucket is its own lowered computation.
     """
-    with jax.named_scope(f"obs.compact_gather.cap{cap}"):
+    with jax.named_scope(f"compaction.gather.cap{cap}"):
         idx = perm[: min(cap, perm.shape[0])]
         return tuple(r[idx] for r in rows)
 
@@ -399,7 +416,7 @@ def scatter_outputs(perm, cap: int, n: int, *outs):
     ``scatter_outputs`` after :func:`compact_targets` is the identity on
     active rows and zero elsewhere.
     """
-    with jax.named_scope(f"obs.compact_scatter.cap{cap}"):
+    with jax.named_scope(f"compaction.scatter.cap{cap}"):
         idx = perm[: min(cap, perm.shape[0])]
         return tuple(
             jnp.zeros((n,) + o.shape[1:], o.dtype).at[idx].set(o)
@@ -422,7 +439,7 @@ def scatter_sources(perm, cap: int, base, upd, mask_c):
     are untouched (an active row is always inside the window when ``cap``
     bounds the active count).
     """
-    with jax.named_scope(f"obs.scatter_sources.cap{cap}"):
+    with jax.named_scope(f"compaction.scatter_sources.cap{cap}"):
         idx = perm[: min(cap, perm.shape[0])]
         m = mask_c[:, None] if upd.ndim == 2 else mask_c
         rows = jnp.where(m, upd.astype(base.dtype), base[idx])
@@ -472,14 +489,17 @@ def acc_jerk_pot_rect(
     n_t, n_s = pos_t.shape[0], pos_s.shape[0]
     nt_pad = _round_up(n_t, block_i)
     ns_pad = _round_up(n_s, block_j)
-    tgt = pack_targets(pos_t, vel_t, nt_pad, mask_t)
-    src = pack_sources(pos_s, vel_s, mass_s, ns_pad)
+    with jax.named_scope(PACK):
+        tgt = pack_targets(pos_t, vel_t, nt_pad, mask_t)
+        src = pack_sources(pos_s, vel_s, mass_s, ns_pad)
     out = nbody_force.acc_jerk_pot_packed(
         tgt, src, eps=eps, block_i=block_i, block_j=block_j,
         interpret=(impl == "pallas_interpret"),
         compute_dtype=compute_dtype,
-    )[:n_t]
-    return out[:, 0:3], out[:, 3:6], out[:, 6]
+    )
+    with jax.named_scope(PACK):
+        out = out[:n_t]
+        return out[:, 0:3], out[:, 3:6], out[:, 6]
 
 
 @partial(jax.jit,
@@ -521,16 +541,18 @@ def snap_rect(
     n_t, n_s = pos_t.shape[0], pos_s.shape[0]
     nt_pad = _round_up(n_t, block_i)
     ns_pad = _round_up(n_s, block_j)
-    tgt = pack_targets(pos_t, vel_t, nt_pad, mask_t)
-    src = pack_sources(pos_s, vel_s, mass_s, ns_pad)
-    tacc = pack_acc_targets(acc_t, nt_pad)
-    sacc = pack_acc_sources(acc_s, ns_pad)
+    with jax.named_scope(PACK):
+        tgt = pack_targets(pos_t, vel_t, nt_pad, mask_t)
+        src = pack_sources(pos_s, vel_s, mass_s, ns_pad)
+        tacc = pack_acc_targets(acc_t, nt_pad)
+        sacc = pack_acc_sources(acc_s, ns_pad)
     out = nbody_force.snap_packed(
         tgt, src, tacc, sacc, eps=eps, block_i=block_i, block_j=block_j,
         interpret=(impl == "pallas_interpret"),
         compute_dtype=compute_dtype,
     )
-    return out[:n_t, 0:3]
+    with jax.named_scope(PACK):
+        return out[:n_t, 0:3]
 
 
 def acc_jerk_pot(pos, vel, mass, **kw):

@@ -177,8 +177,9 @@ def main(argv=None):
     ap.add_argument("--out", default=None, help="JSON report path")
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="write a Chrome-trace/Perfetto JSON of the run "
-                         "(nested macro-step -> event -> kernel-launch "
-                         "spans; load at https://ui.perfetto.dev)")
+                         "(one sim.step span per chunk around sim.engine, "
+                         "sim.readback and sim.diagnostics; load at "
+                         "https://ui.perfetto.dev)")
     ap.add_argument("--metrics-interval", type=int, default=0,
                     help="attach a metrics-registry snapshot to every K-th "
                          "diagnostics record (0 = final snapshot only; the "

@@ -1,30 +1,22 @@
-"""Event-level span tracing: nested host spans -> Chrome-trace/Perfetto JSON.
+"""Host spans on the profiler's clock, and their Chrome-trace/Perfetto export.
 
-A :class:`SpanTracer` records *complete* events (``ph: "X"``) with host
-timestamps relative to the tracer's start; Perfetto (https://ui.perfetto.dev)
-and ``chrome://tracing`` both infer nesting from time containment on one
-track, so a ``with tracer.span("macro-step"): ...`` enclosing
-``tracer.span("event")`` renders as a nested flame.
+Every span enters a ``jax.profiler.TraceAnnotation`` of its name, whichever
+tracer is current, so a captured device profile (``jax.profiler.trace``)
+holds the runner's spans on the same clock as the device's operations.
+The default :class:`NullTracer` does only that: it formats no arguments
+and records nothing in Python.  A :class:`SpanTracer`, installed for a run
+by ``SimConfig.trace`` (CLI ``--trace out.json``), also records each span
+as a complete event (``ph: "X"``) with its arguments and exports them as
+Chrome trace JSON; Perfetto (https://ui.perfetto.dev) and
+``chrome://tracing`` infer nesting from time containment on one track.
 
-Two integration points line host spans up with device activity:
-
-* every live span also enters a ``jax.profiler.TraceAnnotation`` of the same
-  name, so when a device profile is captured (``jax.profiler.trace``) the
-  host spans appear on the profiler timeline next to the XLA ops;
-* traced code is annotated with ``jax.named_scope`` at the emission sites
-  (``sim/ensemble.py``, ``core/strategies.py``, ``kernels/ops.py``), so the
-  HLO itself carries the same taxonomy.
-
-Spans the engine cannot time individually (the per-event work lives inside a
-``lax.scan`` under ``jit``) are reconstructed by the driver as *synthetic*
-spans via :meth:`SpanTracer.add_span` — evenly subdividing a measured chunk,
-flagged ``{"synthetic": true}`` so a reader never mistakes them for measured
-host timestamps.  The aggregate (chunk wall, event count, tiles) is measured;
-only the subdivision is synthetic.
-
-The module-level *current tracer* defaults to a zero-overhead
-:class:`NullTracer`; ``sim/driver.py`` installs a live tracer for the run
-when ``SimConfig.trace`` (CLI ``--trace out.json``) is set.
+The runners (``sim/api.py``) open one ``sim.step`` span per ``step`` with
+the children ``sim.engine`` (the engine call through its
+``block_until_ready``), ``sim.readback`` (device-to-host reads of carry
+counters, time or state) and ``sim.diagnostics`` (energy, counters,
+histograms, the telemetry snapshot).  Inside the compiled programs the
+layers are ``jax.named_scope``s instead (``event_loop.*``,
+``compaction.*``, ``collective.*``); see ``docs/observability.md``.
 """
 
 from __future__ import annotations
@@ -36,23 +28,23 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
-try:  # host-span mirroring onto the device profiler timeline
-    from jax.profiler import TraceAnnotation
-except Exception:  # pragma: no cover - jax always ships it today
-    TraceAnnotation = None
+from jax.profiler import TraceAnnotation
 
 #: schema tag carried in the exported JSON's ``otherData``
 TRACE_SCHEMA_VERSION = 1
 
 
 class NullTracer:
-    """Disabled tracer: every operation is a no-op (the default)."""
+    """Profiler annotations only: spans record nothing in Python."""
 
     enabled = False
 
     @contextmanager
     def span(self, name: str, **args):
-        yield
+        """A ``TraceAnnotation`` of ``name``; ``args`` are not formatted.
+        Yields None (a :class:`SpanTracer` yields the span's args)."""
+        with TraceAnnotation(name):
+            yield None
 
     def add_span(self, name: str, start_us: float, dur_us: float,
                  *, args: Optional[Dict[str, Any]] = None,
@@ -93,23 +85,19 @@ class SpanTracer(NullTracer):
 
     @contextmanager
     def span(self, name: str, **args):
-        """Live nested span; also a ``jax.profiler.TraceAnnotation``."""
+        """Live nested span, also a ``TraceAnnotation``; yields its args,
+        which the caller may extend before the span ends."""
         t0 = self.now_us()
-        ann = TraceAnnotation(name) if TraceAnnotation is not None else None
-        if ann is not None:
-            ann.__enter__()
         try:
-            yield self
+            with TraceAnnotation(name):
+                yield args
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            self.add_span(name, t0, self.now_us() - t0,
-                          args=args or None)
+            self.add_span(name, t0, self.now_us() - t0, args=args or None)
 
     def add_span(self, name: str, start_us: float, dur_us: float,
                  *, args: Optional[Dict[str, Any]] = None,
                  tid: Optional[int] = None) -> None:
-        """Record a span with explicit timestamps (synthetic subdivisions)."""
+        """Record a complete span with explicit timestamps."""
         ev = {"name": name, "ph": "X", "ts": float(start_us),
               "dur": max(float(dur_us), 0.001), "pid": os.getpid(),
               "tid": self._tid() if tid is None else tid, "cat": "sim"}

@@ -272,38 +272,6 @@ def _build_states(cfg: SimConfig):
     ]
 
 
-def _chunk_spans(tracer, t0_us: float, dur_us: float, *, chunk: int,
-                 events: int, tiles: Optional[float] = None,
-                 max_children: int = 256) -> None:
-    """One measured ``macro-step`` span per engine chunk, synthetically
-    subdivided into ``event`` -> ``kernel-launch`` children.
-
-    The per-event work runs inside ``lax.scan`` under ``jit`` — untimeable
-    from the host — so the chunk aggregate (wall, event count, launched
-    tiles) is *measured* and only the even subdivision is synthetic, flagged
-    ``{"synthetic": true}`` on every reconstructed child.
-    """
-    if not tracer.enabled:
-        return
-    args = {"chunk": chunk, "events": int(events)}
-    if tiles is not None:
-        args["tiles"] = float(tiles)
-    tracer.add_span("macro-step", t0_us, dur_us, args=args)
-    n = min(int(events), max_children)
-    if n <= 0:
-        return
-    child = dur_us / n
-    per = {"synthetic": True, "events": int(events) // n}
-    if tiles is not None:
-        per["tiles"] = float(tiles) / n
-    for i in range(n):
-        s = t0_us + i * child
-        tracer.add_span("event", s, child * 0.999, args=per)
-        if tiles is not None:
-            tracer.add_span("kernel-launch", s + 0.1 * child, 0.8 * child,
-                            args=per)
-
-
 def _mix_params(cfg: SimConfig) -> Dict[str, Dict[str, Any]]:
     """Distribute flat CLI params over the mix: each scenario takes the keys
     its registry spec accepts; a key no scenario accepts raises (same
@@ -447,43 +415,50 @@ class SingleRunner(Runner):
         h.e0 = float(nbody.total_energy(state))
         h.recorder.record_snapshot(0, 0.0, energy=h.e0, de_rel=0.0)
         h.state, h.evaluator = state, evaluator
-        h.steps, h.h_prev = 0, None
+        h.steps, h.h_prev, h.t = 0, None, float(state.time)
+        h.finished = not h.t < cfg.t_end
         return h
 
     def step(self, h: RunHandle) -> bool:
         if h.finished:
             return True
-        cfg, state = h.cfg, h.state
-        if not (float(state.time) < cfg.t_end and h.steps < MAX_STEPS):
-            h.finished = True
-            return True
-        if cfg.dt is not None:
-            dt = cfg.dt
-        else:
-            dt = float(hermite.aarseth_dt(state, eta=cfg.eta,
-                                          dt_max=cfg.dt_max))
-            if h.h_prev is not None:  # rate-limit dt changes (robustness)
-                dt = min(max(dt, 0.5 * h.h_prev), 2.0 * h.h_prev)
-            h.h_prev = dt
-        dt = min(dt, cfg.t_end - float(state.time))
-        t0 = time.perf_counter()
-        with obs_trace.get_tracer().span("macro-step", step=h.steps + 1,
-                                         dt=dt):
-            state = hermite.step(state, jnp.asarray(dt, state.dtype),
-                                 h.evaluator, order=cfg.order)
-            jax.block_until_ready(state.pos)
-        h.state = state
-        h.steps += 1
-        obs_metrics.registry().counter(
-            "sim.events", unit="events",
-            help="productive member-events (lockstep: member-steps)").inc()
-        h.recorder.record_step(h.steps, float(state.time),
-                               time.perf_counter() - t0)
-        if h.steps % cfg.diag_every == 0:
-            e = float(nbody.total_energy(state))
-            h.recorder.record_snapshot(h.steps, float(state.time), energy=e,
-                                       de_rel=abs((e - h.e0) / h.e0))
-        return False
+        cfg = h.cfg
+        tracer = obs_trace.get_tracer()
+        with tracer.span("sim.step", step=h.steps + 1) as args:
+            with tracer.span("sim.engine"):
+                if cfg.dt is not None:
+                    dt = cfg.dt
+                else:
+                    dt = float(hermite.aarseth_dt(h.state, eta=cfg.eta,
+                                                  dt_max=cfg.dt_max))
+                    if h.h_prev is not None:  # rate-limit dt changes
+                        dt = min(max(dt, 0.5 * h.h_prev), 2.0 * h.h_prev)
+                    h.h_prev = dt
+                dt = min(dt, cfg.t_end - h.t)
+                t0 = time.perf_counter()
+                state = hermite.step(h.state, jnp.asarray(dt, h.state.dtype),
+                                     h.evaluator, order=cfg.order)
+                jax.block_until_ready(state.pos)
+            h.state = state
+            h.steps += 1
+            if args is not None:
+                args["dt"] = dt
+            with tracer.span("sim.readback"):
+                h.t = float(state.time)
+            with tracer.span("sim.diagnostics"):
+                obs_metrics.registry().counter(
+                    "sim.events", unit="events",
+                    help="productive member-events (lockstep: member-steps)"
+                ).inc()
+                h.recorder.record_step(h.steps, h.t,
+                                       time.perf_counter() - t0)
+                if h.steps % cfg.diag_every == 0:
+                    e = float(nbody.total_energy(state))
+                    h.recorder.record_snapshot(
+                        h.steps, h.t, energy=e,
+                        de_rel=abs((e - h.e0) / h.e0))
+        h.finished = not (h.t < cfg.t_end and h.steps < MAX_STEPS)
+        return h.finished
 
     def collect(self, h: RunHandle) -> RunReport:
         cfg = h.cfg
@@ -564,42 +539,47 @@ class BlockStrategyRunner(Runner):
             return True
         tracer = obs_trace.get_tracer()
         reg = obs_metrics.registry()
-        t0 = time.perf_counter()
-        t0_us = tracer.now_us()
-        h.state, h.carry = ens.strategy_run_block(
-            h.state, t_end=cfg.t_end, n_events=cfg.diag_every,
-            dt_max=cfg.dt_max, n_levels=h.n_levels, carry=h.carry,
-            eta=cfg.eta, order=cfg.order, eps=cfg.eps, impl=h.impl,
-            strategy=cfg.strategy, compaction=cfg.compaction,
-            block_i=cfg.block_i, block_j=cfg.block_j, devices=cfg.devices,
-            dtype=cfg.dtype)
-        jax.block_until_ready(h.state.pos)
-        h.done += 1
-        ev_now = float(h.carry.n_events)
-        tiles_now = float(np.asarray(h.carry.n_tiles).sum())
-        _chunk_spans(tracer, t0_us, tracer.now_us() - t0_us, chunk=h.done,
-                     events=int(ev_now - h.ev_prev),
-                     tiles=tiles_now - h.tiles_prev)
-        reg.counter("sim.events", unit="events").inc(ev_now - h.ev_prev)
-        reg.counter("sim.tiles_launched", unit="tiles").inc(
-            tiles_now - h.tiles_prev)
-        per_shard_now = np.asarray(h.carry.n_tiles, np.float64)
-        if per_shard_now.size and per_shard_now.mean() > 0:
-            reg.gauge(
-                "sim.shard_imbalance", unit="ratio",
-                help="max/mean per-shard launched tiles").set(
-                float(per_shard_now.max() / per_shard_now.mean()))
-        h.ev_prev, h.tiles_prev = ev_now, tiles_now
-        e = float(nbody.total_energy(h.state))
-        h.recorder.record_step(int(h.carry.n_events), float(h.state.time),
-                               time.perf_counter() - t0)
-        h.recorder.record_snapshot(
-            int(h.carry.n_events), float(h.state.time), energy=e,
-            de_rel=abs((e - h.e0) / h.e0),
-            **({"metrics": reg.snapshot()}
-               if cfg.metrics_interval
-               and h.done % cfg.metrics_interval == 0 else {}))
-        if float(h.state.time) >= cfg.t_end:
+        with tracer.span("sim.step", chunk=h.done + 1) as args:
+            t0 = time.perf_counter()
+            with tracer.span("sim.engine"):
+                h.state, h.carry = ens.strategy_run_block(
+                    h.state, t_end=cfg.t_end, n_events=cfg.diag_every,
+                    dt_max=cfg.dt_max, n_levels=h.n_levels, carry=h.carry,
+                    eta=cfg.eta, order=cfg.order, eps=cfg.eps, impl=h.impl,
+                    strategy=cfg.strategy, compaction=cfg.compaction,
+                    block_i=cfg.block_i, block_j=cfg.block_j,
+                    devices=cfg.devices, dtype=cfg.dtype)
+                jax.block_until_ready(h.state.pos)
+            h.done += 1
+            with tracer.span("sim.readback"):
+                ev_now = float(h.carry.n_events)
+                per_shard_now = np.asarray(h.carry.n_tiles, np.float64)
+                t_now = float(h.state.time)
+            tiles_now = float(per_shard_now.sum())
+            if args is not None:
+                args.update(events=int(ev_now - h.ev_prev),
+                            tiles=tiles_now - h.tiles_prev)
+            with tracer.span("sim.diagnostics"):
+                reg.counter("sim.events", unit="events").inc(
+                    ev_now - h.ev_prev)
+                reg.counter("sim.tiles_launched", unit="tiles").inc(
+                    tiles_now - h.tiles_prev)
+                if per_shard_now.size and per_shard_now.mean() > 0:
+                    reg.gauge(
+                        "sim.shard_imbalance", unit="ratio",
+                        help="max/mean per-shard launched tiles").set(
+                        float(per_shard_now.max() / per_shard_now.mean()))
+                h.ev_prev, h.tiles_prev = ev_now, tiles_now
+                e = float(nbody.total_energy(h.state))
+                h.recorder.record_step(int(ev_now), t_now,
+                                       time.perf_counter() - t0)
+                h.recorder.record_snapshot(
+                    int(ev_now), t_now, energy=e,
+                    de_rel=abs((e - h.e0) / h.e0),
+                    **({"metrics": reg.snapshot()}
+                       if cfg.metrics_interval
+                       and h.done % cfg.metrics_interval == 0 else {}))
+        if t_now >= cfg.t_end:
             h.finished = True
         return h.finished
 
@@ -737,148 +717,160 @@ class EnsembleRunner(Runner):
             return True
         step_fn = {"fixed": self._step_fixed, "adaptive": self._step_adaptive,
                    "block": self._step_block}[h.stepper]
-        return step_fn(h)
+        with obs_trace.get_tracer().span(
+                "sim.step", chunk=h.chunks_done + 1) as args:
+            return step_fn(h, args)
 
-    def _step_fixed(self, h: RunHandle) -> bool:
+    def _step_fixed(self, h: RunHandle, args) -> bool:
         cfg = h.cfg
         tracer = obs_trace.get_tracer()
         chunk = min(cfg.diag_every, h.n_steps - h.done)
         t0 = time.perf_counter()
-        t0_us = tracer.now_us()
-        h.batched = ens.ensemble_run(h.batched, n_steps=chunk, dt=cfg.dt,
-                                     **h.kw)
-        jax.block_until_ready(h.batched.pos)
+        with tracer.span("sim.engine"):
+            h.batched = ens.ensemble_run(h.batched, n_steps=chunk, dt=cfg.dt,
+                                         **h.kw)
+            jax.block_until_ready(h.batched.pos)
         h.done += chunk
-        _chunk_spans(tracer, t0_us, tracer.now_us() - t0_us,
-                     chunk=h.chunks_done + 1, events=chunk * h.b)
-        obs_metrics.registry().counter(
-            "sim.events", unit="events").inc(chunk * h.b)
-        self._snapshot(h, h.done, h.done * cfg.dt, time.perf_counter() - t0)
+        if args is not None:
+            args["events"] = chunk * h.b
+        with tracer.span("sim.diagnostics"):
+            obs_metrics.registry().counter(
+                "sim.events", unit="events").inc(chunk * h.b)
+            self._snapshot(h, h.done, h.done * cfg.dt,
+                           time.perf_counter() - t0)
         h.finished = h.done >= h.n_steps
         return h.finished
 
-    def _step_adaptive(self, h: RunHandle) -> bool:
+    def _step_adaptive(self, h: RunHandle, args) -> bool:
         cfg = h.cfg
         tracer = obs_trace.get_tracer()
         t0 = time.perf_counter()
-        t0_us = tracer.now_us()
-        h.batched, h.h_prev, h.n_taken = ens.ensemble_run_adaptive(
-            h.batched, t_end=cfg.t_end, n_steps=cfg.diag_every,
-            h_prev=h.h_prev, n_taken=h.n_taken, eta=cfg.eta,
-            dt_max=cfg.dt_max, **h.kw)
-        jax.block_until_ready(h.batched.pos)
+        with tracer.span("sim.engine"):
+            h.batched, h.h_prev, h.n_taken = ens.ensemble_run_adaptive(
+                h.batched, t_end=cfg.t_end, n_steps=cfg.diag_every,
+                h_prev=h.h_prev, n_taken=h.n_taken, eta=cfg.eta,
+                dt_max=cfg.dt_max, **h.kw)
+            jax.block_until_ready(h.batched.pos)
         h.done += 1
-        ev_now = float(np.asarray(h.n_taken, np.float64).sum())
-        _chunk_spans(tracer, t0_us, tracer.now_us() - t0_us,
-                     chunk=h.done, events=int(ev_now - h.ev_prev))
-        obs_metrics.registry().counter(
-            "sim.events", unit="events").inc(ev_now - h.ev_prev)
-        h.ev_prev = ev_now
-        self._snapshot(h, int(np.max(np.asarray(h.n_taken))),
-                       float(np.min(np.asarray(h.batched.time))),
-                       time.perf_counter() - t0)
-        h.finished = (float(np.min(np.asarray(h.batched.time))) >= cfg.t_end
+        with tracer.span("sim.readback"):
+            n_taken = np.asarray(h.n_taken, np.float64)
+            t_min = float(np.min(np.asarray(h.batched.time)))
+        ev_now = float(n_taken.sum())
+        if args is not None:
+            args["events"] = int(ev_now - h.ev_prev)
+        with tracer.span("sim.diagnostics"):
+            obs_metrics.registry().counter(
+                "sim.events", unit="events").inc(ev_now - h.ev_prev)
+            h.ev_prev = ev_now
+            self._snapshot(h, int(np.max(n_taken)), t_min,
+                           time.perf_counter() - t0)
+        h.finished = (t_min >= cfg.t_end
                       or h.done * cfg.diag_every >= MAX_STEPS)
         return h.finished
 
-    def _step_block(self, h: RunHandle) -> bool:
+    def _step_block(self, h: RunHandle, args) -> bool:
         cfg = h.cfg
         tracer = obs_trace.get_tracer()
         reg = obs_metrics.registry()
+        # the fused mesh engine's capacity switch lives inside the shards
+        # (one shared bucket per (batch, domain) shard): there is no
+        # batch-level hit distribution to report
+        batch_hits = cfg.compaction == "gather" and cfg.mesh is None
         t0 = time.perf_counter()
-        t0_us = tracer.now_us()
-        h.batched, h.carry = ens.ensemble_run_block(
-            h.batched, t_end=cfg.t_end, n_events=cfg.diag_every,
-            dt_max=cfg.dt_max, n_levels=h.n_levels, carry=h.carry,
-            eta=cfg.eta, compaction=cfg.compaction,
-            bucket_mode=cfg.bucket_mode,
-            block_i=cfg.block_i, block_j=cfg.block_j,
-            sources=cfg.sources, neighbor_radius=cfg.neighbor_radius,
-            refresh_levels=cfg.refresh_levels, **h.kw)
-        jax.block_until_ready(h.batched.pos)
+        with tracer.span("sim.engine"):
+            h.batched, h.carry = ens.ensemble_run_block(
+                h.batched, t_end=cfg.t_end, n_events=cfg.diag_every,
+                dt_max=cfg.dt_max, n_levels=h.n_levels, carry=h.carry,
+                eta=cfg.eta, compaction=cfg.compaction,
+                bucket_mode=cfg.bucket_mode,
+                block_i=cfg.block_i, block_j=cfg.block_j,
+                sources=cfg.sources, neighbor_radius=cfg.neighbor_radius,
+                refresh_levels=cfg.refresh_levels, **h.kw)
+            jax.block_until_ready(h.batched.pos)
         h.done += 1
-        ev = np.asarray(h.carry.n_events, np.float64)
-        tiles = np.asarray(h.carry.n_tiles, np.float64)
-        pairs = np.asarray(h.carry.n_pairs, np.float64)
+        nbr = h.carry.nbr
+        with tracer.span("sim.readback"):
+            ev = np.asarray(h.carry.n_events, np.float64)
+            tiles = np.asarray(h.carry.n_tiles, np.float64)
+            pairs = np.asarray(h.carry.n_pairs, np.float64)
+            t_min = float(np.min(np.asarray(h.batched.time)))
+            if batch_hits:
+                hits = np.asarray(h.carry.bucket_hits, np.float64)
+            if nbr is not None:
+                nref = float(np.asarray(nbr.n_refresh, np.float64).sum())
+                nov = float(np.asarray(nbr.n_overflow, np.float64).sum())
+                wc = np.asarray(nbr.win_cnt, np.float64)
         ev_d, tiles_d = ev - h.ev_prev, tiles - h.tiles_prev
         pairs_d = pairs - h.pairs_prev
-        _chunk_spans(tracer, t0_us, tracer.now_us() - t0_us, chunk=h.done,
-                     events=int(ev_d.sum()), tiles=float(tiles_d.sum()))
-        reg.counter("sim.events", unit="events").inc(float(ev_d.sum()))
-        reg.counter("sim.tiles_launched", unit="tiles").inc(
-            float(tiles_d.sum()))
-        reg.counter(
-            "sim.tiles_dense_baseline", unit="tiles",
-            help="what compaction='none' would have enqueued").inc(
-            float(ev_d.sum()) * h.plan.dense_tiles)
-        # analytic a-priori tile bound: occupancy entry 0 (every real
-        # particle) is the largest active set any tick of the block
-        # schedule can see, so per member and event the launch can
-        # never exceed the tiles of occ[0]'s capacity bucket
-        # the full-N tile bound doesn't transfer to the fused mesh, whose
-        # launches are sized by P shard-local plans (the engine already
-        # schedules from the analytic per-shard bound there)
-        if cfg.mesh is None:
-            occ0 = np.asarray(jax.vmap(
-                lambda lv, m: hermite.block_level_occupancy(
-                    lv, n_levels=h.n_levels, mask=m))(
-                        h.carry.levels, jnp.asarray(h.mask)))[:, 0]
+        if args is not None:
+            args.update(events=int(ev_d.sum()), tiles=float(tiles_d.sum()))
+        with tracer.span("sim.diagnostics"):
+            reg.counter("sim.events", unit="events").inc(float(ev_d.sum()))
+            reg.counter("sim.tiles_launched", unit="tiles").inc(
+                float(tiles_d.sum()))
+            reg.counter(
+                "sim.tiles_dense_baseline", unit="tiles",
+                help="what compaction='none' would have enqueued").inc(
+                float(ev_d.sum()) * h.plan.dense_tiles)
+            # analytic a-priori tile bound: occupancy entry 0 (every real
+            # particle) is the largest active set any tick of the block
+            # schedule can see, so per member and event the launch can
+            # never exceed the tiles of occ[0]'s capacity bucket
+            # the full-N tile bound doesn't transfer to the fused mesh,
+            # whose launches are sized by P shard-local plans (the engine
+            # already schedules from the analytic per-shard bound there)
+            if cfg.mesh is None:
+                occ0 = np.asarray(jax.vmap(
+                    lambda lv, m: hermite.block_level_occupancy(
+                        lv, n_levels=h.n_levels, mask=m))(
+                            h.carry.levels, jnp.asarray(h.mask)))[:, 0]
+                for i in range(h.b):
+                    per_event = (int(h.plan.tiles(h.plan.bucket(
+                        int(occ0[i])))) if cfg.compaction == "gather"
+                                 else h.plan.dense_tiles)
+                    h.bound_total += ev_d[i] * per_event
+                reg.gauge("sim.tiles_occupancy_bound", unit="tiles",
+                          help="analytic bound; launched <= bound").set(
+                    h.bound_total)
             for i in range(h.b):
-                per_event = (int(h.plan.tiles(h.plan.bucket(int(occ0[i]))))
-                             if cfg.compaction == "gather"
-                             else h.plan.dense_tiles)
-                h.bound_total += ev_d[i] * per_event
-            reg.gauge("sim.tiles_occupancy_bound", unit="tiles",
-                      help="analytic bound; launched <= bound").set(
-                h.bound_total)
-        for i in range(h.b):
-            if ev_d[i] > 0 and h.n_active[i] > 0:
-                reg.histogram(
-                    "sim.active_fraction", unit="fraction",
-                    help="per-chunk mean active-target fraction"
-                ).observe(pairs_d[i]
-                          / (ev_d[i] * float(h.n_active[i]) ** 2))
-        # the fused mesh engine's capacity switch lives inside the shards
-        # (one shared bucket per (batch, domain) shard) — there is no
-        # batch-level hit distribution to report
-        if cfg.compaction == "gather" and cfg.mesh is None:
-            reg.gauge(
-                "sim.bucket_hits", unit="hits",
-                help="capacity-bucket switch hit counts (full "
-                     "schedule, summed over members)").set(
-                [float(hits) for hits in
-                 np.asarray(h.carry.bucket_hits, np.float64).sum(axis=0)])
-        if h.carry.nbr is not None:
-            nbr = h.carry.nbr
-            nref = float(np.asarray(nbr.n_refresh, np.float64).sum())
-            nov = float(np.asarray(nbr.n_overflow, np.float64).sum())
-            reg.counter(
-                "sim.neighbor_refreshes", unit="refreshes",
-                help="Ahmad-Cohen window rebuilds (far-field "
-                     "re-anchors, summed over members)").inc(
-                nref - h.nref_prev)
-            reg.counter(
-                "sim.neighbor_overflow", unit="fallbacks",
-                help="refreshes whose widest active window fit no "
-                     "bucket below the full source extent").inc(
-                nov - h.nov_prev)
-            h.nref_prev, h.nov_prev = nref, nov
-            wc = np.asarray(nbr.win_cnt, np.float64)
-            nsb = nbr.win_idx.shape[-1]
-            blk_valid = (np.arange(wc.shape[1])[None, :]
-                         * h.plan.block_i) \
-                < np.asarray(h.n_active)[:, None]
-            occ_hist = reg.histogram(
-                "sim.neighbor_occupancy", unit="fraction",
-                help="per-target-block neighbor window fraction of "
-                     "the full source extent (sampled per chunk)")
-            for v in (wc[blk_valid] / nsb).tolist():
-                occ_hist.observe(v)
-        h.ev_prev, h.tiles_prev, h.pairs_prev = ev, tiles, pairs
-        self._snapshot(h, int(np.max(np.asarray(h.carry.n_events))),
-                       float(np.min(np.asarray(h.batched.time))),
-                       time.perf_counter() - t0)
-        h.finished = (float(np.min(np.asarray(h.batched.time))) >= cfg.t_end
+                if ev_d[i] > 0 and h.n_active[i] > 0:
+                    reg.histogram(
+                        "sim.active_fraction", unit="fraction",
+                        help="per-chunk mean active-target fraction"
+                    ).observe(pairs_d[i]
+                              / (ev_d[i] * float(h.n_active[i]) ** 2))
+            if batch_hits:
+                reg.gauge(
+                    "sim.bucket_hits", unit="hits",
+                    help="capacity-bucket switch hit counts (full "
+                         "schedule, summed over members)").set(
+                    [float(x) for x in hits.sum(axis=0)])
+            if nbr is not None:
+                reg.counter(
+                    "sim.neighbor_refreshes", unit="refreshes",
+                    help="Ahmad-Cohen window rebuilds (far-field "
+                         "re-anchors, summed over members)").inc(
+                    nref - h.nref_prev)
+                reg.counter(
+                    "sim.neighbor_overflow", unit="fallbacks",
+                    help="refreshes whose widest active window fit no "
+                         "bucket below the full source extent").inc(
+                    nov - h.nov_prev)
+                h.nref_prev, h.nov_prev = nref, nov
+                nsb = nbr.win_idx.shape[-1]
+                blk_valid = (np.arange(wc.shape[1])[None, :]
+                             * h.plan.block_i) \
+                    < np.asarray(h.n_active)[:, None]
+                occ_hist = reg.histogram(
+                    "sim.neighbor_occupancy", unit="fraction",
+                    help="per-target-block neighbor window fraction of "
+                         "the full source extent (sampled per chunk)")
+                for v in (wc[blk_valid] / nsb).tolist():
+                    occ_hist.observe(v)
+            h.ev_prev, h.tiles_prev, h.pairs_prev = ev, tiles, pairs
+            self._snapshot(h, int(np.max(ev)), t_min,
+                           time.perf_counter() - t0)
+        h.finished = (t_min >= cfg.t_end
                       or h.done * cfg.diag_every >= MAX_STEPS)
         return h.finished
 

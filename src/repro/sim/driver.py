@@ -18,7 +18,6 @@ from repro.sim.api import (  # noqa: F401
     SimConfig,
     _auto_levels,
     _build_states,
-    _chunk_spans,
     _device_list,
     _mix_params,
     get_runner,

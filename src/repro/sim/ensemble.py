@@ -609,11 +609,7 @@ def _event_pre(s, t_last, levels, dt_macro, na, t_end, *, n_sub: int):
 
     xp, vp = hermite.predict(s, h)
     ap = hermite.predict_acc(s, h)
-    # active targets first (argsort of the negated mask); row order
-    # within the gathered buffer is irrelevant to the row-local kernel
-    # math, the permutation only densifies the launch
-    perm = jnp.argsort(~active, stable=True)
-    return live, t_next, active, h, xp, vp, ap, perm
+    return live, t_next, active, h, xp, vp, ap
 
 
 def _event_post(s, ev, live, t_next, active, h, t_last, levels,
@@ -859,54 +855,61 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
 
         def body(acc, _):
             s, c = acc
-            with jax.named_scope("event.pre"):
-                live, t_next, active, h, xp, vp, ap, perm = jax.vmap(
+            with jax.named_scope("event_loop.pre"):
+                live, t_next, active, h, xp, vp, ap = jax.vmap(
                     member_pre, in_axes=(0, 0, 0, 0, 0, 0))(
                         s, c.t_last, c.levels, c.dt_macro, n_active, t_end)
             hits_event = None
             if compaction == "gather":
-                n_act = jnp.sum(active, axis=1).astype(jnp.int32)
+                perm = jax.vmap(ops.active_first)(active)
+                with jax.named_scope("compaction.bucket_index"):
+                    n_act = jnp.sum(active, axis=1).astype(jnp.int32)
                 n_caps_full = c.bucket_hits.shape[1]
                 evs, tiles_parts, hits_parts = [], [], []
-                for gi, (members, gplan, gbev) in enumerate(group_data):
-                    with jax.named_scope(f"event.bucket_switch.g{gi}"):
+                for members, gplan, gbev in group_data:
+                    with jax.named_scope("compaction.bucket_index"):
                         cap_idx = shared_cap_index(gplan, jnp.where(
                             live[members], n_act[members], 0))
-                        evs.append(_batch_map(
-                            gbev, mesh, in_axes=(0, 0, 0, 0, 0, 0, None))(
-                                xp[members], vp[members], ap[members],
-                                s.mass[members], active[members],
-                                perm[members], cap_idx))
-                    tiles_parts.append(jnp.broadcast_to(
-                        gplan.tiles(cap_idx).astype(count_dtype),
-                        (len(members),)))
-                    hits_parts.append(jnp.broadcast_to(
-                        jax.nn.one_hot(cap_idx, n_caps_full,
-                                       dtype=count_dtype),
-                        (len(members), n_caps_full)))
-                ev = jax.tree_util.tree_map(
-                    lambda *xs: jnp.concatenate(xs)[inv], *evs)
-                tiles_event = jnp.concatenate(tiles_parts)[inv]
-                hits_event = jnp.concatenate(hits_parts)[inv]
+                    with jax.named_scope("compaction.group"):
+                        operands = (xp[members], vp[members], ap[members],
+                                    s.mass[members], active[members],
+                                    perm[members])
+                    evs.append(_batch_map(
+                        gbev, mesh, in_axes=(0, 0, 0, 0, 0, 0, None))(
+                            *operands, cap_idx))
+                    with jax.named_scope("event_loop.carry"):
+                        tiles_parts.append(jnp.broadcast_to(
+                            gplan.tiles(cap_idx).astype(count_dtype),
+                            (len(members),)))
+                        hits_parts.append(jnp.broadcast_to(
+                            jax.nn.one_hot(cap_idx, n_caps_full,
+                                           dtype=count_dtype),
+                            (len(members), n_caps_full)))
+                with jax.named_scope("compaction.group"):
+                    ev = jax.tree_util.tree_map(
+                        lambda *xs: jnp.concatenate(xs)[inv], *evs)
+                with jax.named_scope("event_loop.carry"):
+                    tiles_event = jnp.concatenate(tiles_parts)[inv]
+                    hits_event = jnp.concatenate(hits_parts)[inv]
             else:
-                with jax.named_scope("event.force"):
-                    ev = _batch_map(bev, mesh)(xp, vp, ap, s.mass, active)
+                ev = _batch_map(bev, mesh)(xp, vp, ap, s.mass, active)
                 tiles_event = jnp.asarray(full_tiles, count_dtype)
-            with jax.named_scope("event.post"):
+            with jax.named_scope("event_loop.post"):
                 s1, t_last, levels, dt_macro, dp, live = jax.vmap(
                     member_post,
                     in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))(
                         s, ev, live, t_next, active, h, c.t_last, c.levels,
                         c.dt_macro, n_active, t_end)
-            c1 = BlockCarry(t_last=t_last, levels=levels, dt_macro=dt_macro,
-                            n_pairs=c.n_pairs + dp,
-                            n_events=c.n_events + live.astype(jnp.int32),
-                            n_tiles=c.n_tiles + jnp.where(live, tiles_event,
-                                                          0.0),
-                            bucket_hits=c.bucket_hits
-                            if hits_event is None else c.bucket_hits
-                            + jnp.where(live[:, None], hits_event, 0.0))
-            return (_constrain(s1, mesh), c1), None
+            with jax.named_scope("event_loop.carry"):
+                c1 = BlockCarry(
+                    t_last=t_last, levels=levels, dt_macro=dt_macro,
+                    n_pairs=c.n_pairs + dp,
+                    n_events=c.n_events + live.astype(jnp.int32),
+                    n_tiles=c.n_tiles + jnp.where(live, tiles_event, 0.0),
+                    bucket_hits=c.bucket_hits
+                    if hits_event is None else c.bucket_hits
+                    + jnp.where(live[:, None], hits_event, 0.0))
+                return (_constrain(s1, mesh), c1), None
 
         step_body = body
         if sources == "neighbor":
@@ -922,8 +925,8 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
             def neighbor_body(acc, _):
                 s, c = acc
                 nb = c.nbr
-                with jax.named_scope("event.pre"):
-                    live, t_next, active, h, xp, vp, ap, _ = jax.vmap(
+                with jax.named_scope("event_loop.pre"):
+                    live, t_next, active, h, xp, vp, ap = jax.vmap(
                         member_pre, in_axes=(0, 0, 0, 0, 0, 0))(
                             s, c.t_last, c.levels, c.dt_macro, n_active,
                             t_end)
@@ -1007,7 +1010,7 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                     # refresh anchor: full force at the event's predicted
                     # positions; new windows from the same positions; far =
                     # full - near with IDENTICAL acc operands in both
-                    with jax.named_scope("event.neighbor_refresh"):
+                    with jax.named_scope("event_loop.neighbor_refresh"):
                         ev_f = _batch_map(bev, mesh)(xp, vp, ap, s.mass,
                                                      real)
                     win_idx_n, win_cnt_n = jax.vmap(
@@ -1078,33 +1081,37 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
                                   nb.pot_far),
                         tref, need.astype(jnp.int32), dov, dp, tiles)
 
-                with jax.named_scope("event.neighbor"):
+                with jax.named_scope("event_loop.neighbor"):
                     (ev, wi, wc, accf, jerkf, snapf, potf, tref, dref,
                      dov, dp, tiles) = jax.lax.cond(
                         jnp.any(need), do_refresh, no_refresh, None)
-                with jax.named_scope("event.post"):
+                with jax.named_scope("event_loop.post"):
                     s1, t_last, levels, dt_macro, _, live = jax.vmap(
                         member_post,
                         in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))(
                             s, ev, live, t_next, active, h, c.t_last,
                             c.levels, c.dt_macro, n_active, t_end)
-                c1 = BlockCarry(
-                    t_last=t_last, levels=levels, dt_macro=dt_macro,
-                    n_pairs=c.n_pairs + dp,
-                    n_events=c.n_events + live.astype(jnp.int32),
-                    n_tiles=c.n_tiles + tiles,
-                    bucket_hits=c.bucket_hits,
-                    nbr=NeighborCarry(
-                        win_idx=wi, win_cnt=wc, acc_far=accf,
-                        jerk_far=jerkf, snap_far=snapf, pot_far=potf,
-                        t_ref=tref, n_refresh=nb.n_refresh + dref,
-                        n_overflow=nb.n_overflow + dov))
-                return (_constrain(s1, mesh), c1), None
+                with jax.named_scope("event_loop.carry"):
+                    c1 = BlockCarry(
+                        t_last=t_last, levels=levels, dt_macro=dt_macro,
+                        n_pairs=c.n_pairs + dp,
+                        n_events=c.n_events + live.astype(jnp.int32),
+                        n_tiles=c.n_tiles + tiles,
+                        bucket_hits=c.bucket_hits,
+                        nbr=NeighborCarry(
+                            win_idx=wi, win_cnt=wc, acc_far=accf,
+                            jerk_far=jerkf, snap_far=snapf, pot_far=potf,
+                            t_ref=tref, n_refresh=nb.n_refresh + dref,
+                            n_overflow=nb.n_overflow + dov))
+                    return (_constrain(s1, mesh), c1), None
 
             step_body = neighbor_body
 
-        (batched, carry), _ = jax.lax.scan(step_body, (batched, carry),
-                                           None, length=n_events)
+        # the scan's own loop control, and the copies XLA makes of the
+        # carried state, belong to the event loop's carry
+        with jax.named_scope("event_loop.carry"):
+            (batched, carry), _ = jax.lax.scan(step_body, (batched, carry),
+                                               None, length=n_events)
         return batched, carry
 
     @jax.jit
@@ -1202,33 +1209,35 @@ def _fused_block_engine(mesh, order: int, eps: float, impl: str,
 
         def body(acc, _):
             s, c = acc
-            with jax.named_scope("event.pre"):
-                live, t_next, active, h, xp, vp, ap, _ = jax.vmap(
+            with jax.named_scope("event_loop.pre"):
+                live, t_next, active, h, xp, vp, ap = jax.vmap(
                     member_pre, in_axes=(0, 0, 0, 0, 0, 0))(
                         s, c.t_last, c.levels, c.dt_macro, n_active, t_end)
-            with jax.named_scope("event.force"):
+            with jax.named_scope("compaction.bucket_index"):
                 bound = jax.vmap(member_bound)(c.levels, n_active, t_next)
                 bound = jnp.where(live[:, None], bound, 0)
-                ev, tiles = bev(xp, vp, ap, s.mass, active, bound)
-            with jax.named_scope("event.post"):
+            ev, tiles = bev(xp, vp, ap, s.mass, active, bound)
+            with jax.named_scope("event_loop.post"):
                 s1, t_last, levels, dt_macro, dp, live = jax.vmap(
                     member_post, in_axes=(0,) * 11)(
                         s, ev, live, t_next, active, h, c.t_last, c.levels,
                         c.dt_macro, n_active, t_end)
-            tiles_m = jnp.sum(tiles, axis=1).astype(count_dtype)
-            c1 = BlockCarry(t_last=t_last, levels=levels, dt_macro=dt_macro,
-                            n_pairs=c.n_pairs + dp,
-                            n_events=c.n_events + live.astype(jnp.int32),
-                            n_tiles=c.n_tiles + jnp.where(live, tiles_m,
-                                                          0.0),
-                            # the shared switch lives inside the shards (one
-                            # bucket per shard, not per member) — no
-                            # batch-level hit distribution to report
-                            bucket_hits=c.bucket_hits)
-            return (_constrain(s1, mesh), c1), None
+            with jax.named_scope("event_loop.carry"):
+                tiles_m = jnp.sum(tiles, axis=1).astype(count_dtype)
+                c1 = BlockCarry(
+                    t_last=t_last, levels=levels, dt_macro=dt_macro,
+                    n_pairs=c.n_pairs + dp,
+                    n_events=c.n_events + live.astype(jnp.int32),
+                    n_tiles=c.n_tiles + jnp.where(live, tiles_m, 0.0),
+                    # the shared switch lives inside the shards (one
+                    # bucket per shard, not per member) — no batch-level
+                    # hit distribution to report
+                    bucket_hits=c.bucket_hits)
+                return (_constrain(s1, mesh), c1), None
 
-        (batched, carry), _ = jax.lax.scan(body, (batched, carry), None,
-                                           length=n_events)
+        with jax.named_scope("event_loop.carry"):
+            (batched, carry), _ = jax.lax.scan(body, (batched, carry), None,
+                                               length=n_events)
         return batched, carry
 
     @jax.jit
@@ -1527,12 +1536,11 @@ def _strategy_block_engine(strategy: str, n_devices: int,
 
         def body(acc, _):
             s, c = acc
-            with jax.named_scope("event.pre"):
-                live, t_next, active, h, xp, vp, ap, _ = event_pre(
+            with jax.named_scope("event_loop.pre"):
+                live, t_next, active, h, xp, vp, ap = event_pre(
                     s, c.t_last, c.levels, c.dt_macro, n, t_end)
-            # the shard-local permutations live inside the shards — the
-            # global argsort from event_pre is not used here
-            with jax.named_scope("event.force"):
+            # the shard-local permutations live inside the shards
+            with jax.named_scope("compaction.bucket_index"):
                 # host-side bucket sizing: padded rows carry level -1, so
                 # each shard's contiguous chunk counts only real particles
                 # at or above the tick's threshold level
@@ -1545,21 +1553,24 @@ def _strategy_block_engine(strategy: str, n_devices: int,
                     lambda lv: hermite.block_level_occupancy(
                         lv, n_levels=n_levels)[thr]
                 )(lev_pad.reshape(n_devices, -1))
-                ev, tiles = bev(xp, vp, ap, s.mass, active, bound)
-            with jax.named_scope("event.post"):
+            ev, tiles = bev(xp, vp, ap, s.mass, active, bound)
+            with jax.named_scope("event_loop.post"):
                 s1, t_last, levels, dt_macro, dp, live = event_post(
                     s, ev, live, t_next, active, h, c.t_last, c.levels,
                     c.dt_macro, n, t_end)
-            c1 = BlockCarry(t_last=t_last, levels=levels, dt_macro=dt_macro,
-                            n_pairs=c.n_pairs + dp,
-                            n_events=c.n_events + live.astype(jnp.int32),
-                            n_tiles=c.n_tiles + jnp.where(
-                                live, tiles, 0).astype(count_dtype),
-                            bucket_hits=c.bucket_hits)
-            return (s1, c1), None
+            with jax.named_scope("event_loop.carry"):
+                c1 = BlockCarry(
+                    t_last=t_last, levels=levels, dt_macro=dt_macro,
+                    n_pairs=c.n_pairs + dp,
+                    n_events=c.n_events + live.astype(jnp.int32),
+                    n_tiles=c.n_tiles + jnp.where(
+                        live, tiles, 0).astype(count_dtype),
+                    bucket_hits=c.bucket_hits)
+                return (s1, c1), None
 
-        (state, carry), _ = jax.lax.scan(body, (state, carry), None,
-                                         length=n_events)
+        with jax.named_scope("event_loop.carry"):
+            (state, carry), _ = jax.lax.scan(body, (state, carry), None,
+                                             length=n_events)
         return state, carry
 
     @jax.jit

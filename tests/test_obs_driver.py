@@ -1,7 +1,8 @@
 """Driver/CLI observability plumbing + the tentpole acceptance criteria:
 
 * a traced ``block``+``gather`` run exports Perfetto-loadable Chrome-trace
-  JSON with nested macro-step -> event -> kernel-launch spans;
+  JSON with one ``sim.step`` span per chunk holding ``sim.engine``,
+  ``sim.readback`` and ``sim.diagnostics``, for every runner kind;
 * the telemetry ``metrics`` payload reports launched tiles within the
   analytic ``hermite.block_level_occupancy`` bound;
 * ``--trace`` / ``--metrics-interval`` thread from the CLI through
@@ -22,6 +23,8 @@ BLOCK_KW = dict(scenario="plummer", n=32, ensemble=2, t_end=0.0625,
                 stepper="block", dt_max=0.0625, n_levels=3,
                 compaction="gather", block_i=8, block_j=32,
                 impl="xla", diag_every=4, validate_ic=False)
+#: the children of every ``sim.step`` span, in the order a step runs them
+STEP_CHILDREN = ("sim.engine", "sim.readback", "sim.diagnostics")
 
 
 @pytest.fixture(scope="module")
@@ -40,35 +43,87 @@ def test_trace_exported_and_loadable(traced_run):
     assert isinstance(doc["traceEvents"], list) and doc["traceEvents"]
 
 
-def test_trace_has_nested_span_taxonomy(traced_run):
-    _, doc = traced_run
+def _by_name(doc):
     by = {}
     for ev in doc["traceEvents"]:
         by.setdefault(ev["name"], []).append(ev)
-    assert by.get("macro-step") and by.get("event") and by.get(
-        "kernel-launch")
-    # every synthetic child sits inside a measured macro-step (Perfetto
-    # infers nesting from exactly this time containment)
-    def inside(child, parent, tol=1.0):
-        return (parent["ts"] <= child["ts"] + tol and
-                child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
-                + tol)
-    for name in ("event", "kernel-launch"):
+    return by
+
+
+def _inside(child, parent, tol=1.0):
+    return (parent["ts"] <= child["ts"] + tol and
+            child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + tol)
+
+
+def _check_step_spans(doc, children=STEP_CHILDREN):
+    """Every step child nests in a ``sim.step``, and each step holds
+    exactly ``children``, once each and in that order; every span is
+    measured."""
+    by = _by_name(doc)
+    steps = by.get("sim.step", [])
+    assert steps
+    for name in children:
+        assert by.get(name), f"no {name} span"
         for child in by[name]:
-            assert child["args"]["synthetic"] is True
-            assert any(inside(child, ms) for ms in by["macro-step"]), \
+            assert any(_inside(child, st) for st in steps), \
                 f"orphan {name} span at ts={child['ts']}"
-    for kl in by["kernel-launch"]:
-        assert any(inside(kl, ev) for ev in by["event"])
+    for st in steps:
+        kids = sorted((e for n in children for e in by[n]
+                       if _inside(e, st)), key=lambda e: e["ts"])
+        assert [e["name"] for e in kids] == list(children)
+    assert not {"macro-step", "event", "kernel-launch"} & set(by)
+    assert not any((e.get("args") or {}).get("synthetic")
+                   for e in doc["traceEvents"])
+
+
+def test_trace_has_nested_span_taxonomy(traced_run):
+    _, doc = traced_run
+    _check_step_spans(doc)
 
 
 def test_macro_step_args_carry_measured_aggregates(traced_run):
+    """The measured chunk aggregates ride on the ``sim.step`` spans."""
     report, doc = traced_run
-    macro = [e for e in doc["traceEvents"] if e["name"] == "macro-step"]
-    assert sum(e["args"]["events"] for e in macro) == \
+    steps = _by_name(doc)["sim.step"]
+    assert [e["args"]["chunk"] for e in steps] == \
+        list(range(1, len(steps) + 1))
+    assert sum(e["args"]["events"] for e in steps) == \
         sum(r["steps"] for r in report["runs"])
-    assert sum(e["args"]["tiles"] for e in macro) == pytest.approx(
+    assert sum(e["args"]["tiles"] for e in steps) == pytest.approx(
         report["grid_tiles_total"])
+
+
+#: one small run of each runner kind; the fixed-dt ensemble reads nothing
+#: back between its engine call and its energy snapshot
+RUNNER_KINDS = {
+    "single": (dict(scenario="plummer", n=32, t_end=0.0625, dt=1 / 64,
+                    impl="xla", diag_every=2, validate_ic=False),
+               STEP_CHILDREN),
+    "single_adaptive": (dict(scenario="plummer", n=32, t_end=0.03125,
+                             impl="xla", diag_every=2, validate_ic=False),
+                        STEP_CHILDREN),
+    "block_strategy": (dict(BLOCK_KW, ensemble=1, strategy="replicated",
+                            devices=1), STEP_CHILDREN),
+    "ensemble_fixed": (dict(scenario="plummer", n=32, ensemble=2,
+                            t_end=0.0625, dt=1 / 64, impl="xla",
+                            diag_every=2, validate_ic=False),
+                       ("sim.engine", "sim.diagnostics")),
+    "ensemble_adaptive": (dict(scenario="plummer", n=32, ensemble=2,
+                               t_end=0.03125, impl="xla", diag_every=2,
+                               validate_ic=False), STEP_CHILDREN),
+    "mixed_block": (dict(mix=(("plummer", 16), ("plummer", 32)),
+                         t_end=0.0625, stepper="block", dt_max=0.0625,
+                         n_levels=2, impl="xla", diag_every=4,
+                         validate_ic=False), STEP_CHILDREN),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(RUNNER_KINDS))
+def test_step_spans_nest_for_every_runner_kind(kind, tmp_path):
+    kw, children = RUNNER_KINDS[kind]
+    path = str(tmp_path / "trace.json")
+    driver.run(driver.SimConfig(trace=path, **kw))
+    _check_step_spans(json.load(open(path)), children)
 
 
 def test_metrics_payload_in_report(traced_run):
@@ -148,7 +203,7 @@ def test_cli_threads_trace_and_metrics_interval(tmp_path, capsys):
     obs_metrics.validate_snapshot(report["metrics"])
     doc = json.load(open(trace_path))
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"macro-step", "event", "kernel-launch"} <= names
+    assert set(STEP_CHILDREN) | {"sim.step"} <= names
 
 
 def test_mixed_run_reports_pad_waste():

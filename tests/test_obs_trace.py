@@ -15,6 +15,47 @@ def test_null_tracer_is_inert():
     assert t.export("/nonexistent/should/never/be/written.json") is None
 
 
+class _Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records entries."""
+
+    log = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.kwargs))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.kwargs))
+        return False
+
+
+def test_null_tracer_enters_trace_annotation(monkeypatch):
+    """With no tracer installed a span still reaches the profiler, by name
+    alone: its args are neither formatted nor recorded."""
+    monkeypatch.setattr(trace, "TraceAnnotation", _Recorder)
+    _Recorder.log = []
+    t = trace.NullTracer()
+    with t.span("sim.engine", chunk=3) as args:
+        assert args is None
+        assert _Recorder.log == [("enter", "sim.engine", {})]
+    assert _Recorder.log[-1] == ("exit", "sim.engine", {})
+
+
+def test_span_tracer_annotates_and_records_extended_args(monkeypatch):
+    monkeypatch.setattr(trace, "TraceAnnotation", _Recorder)
+    _Recorder.log = []
+    t = trace.SpanTracer()
+    with t.span("sim.step", chunk=1) as args:
+        args["events"] = 126
+    assert [e[:2] for e in _Recorder.log] == [("enter", "sim.step"),
+                                             ("exit", "sim.step")]
+    (ev,) = t.events
+    assert ev["args"] == {"chunk": 1, "events": 126}
+
+
 def test_span_records_complete_event():
     t = trace.SpanTracer()
     with t.span("outer", key="v"):
@@ -36,12 +77,13 @@ def test_nested_spans_contained_in_time():
 
 
 def test_add_span_synthetic_and_instant():
+    """Explicit-timestamp spans keep their args; instants are markers."""
     t = trace.SpanTracer()
-    t.add_span("event", 10.0, 5.0, args={"synthetic": True})
+    t.add_span("event", 10.0, 5.0, args={"chunk": 2})
     t.add_span("degenerate", 0.0, 0.0)  # dur clamped to a visible sliver
     t.instant("marker", n=3)
     by = {e["name"]: e for e in t.events}
-    assert by["event"]["args"]["synthetic"] is True
+    assert by["event"]["args"] == {"chunk": 2}
     assert by["degenerate"]["dur"] == 0.001
     assert by["marker"]["ph"] == "i"
 

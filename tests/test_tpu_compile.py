@@ -169,3 +169,16 @@ def test_block_engine_gather_compiles(topo, no_persistent_cache, layout):
     text = run.lower(batched, carry, na, t_end, n_events=16).compile() \
         .as_text()
     assert "tpu_custom_call" in text
+
+
+def test_block_engine_layer_scopes_reach_the_tpu_program(
+        one_chip, no_persistent_cache):
+    """The chip's own program of the event loop (N = 1024, gather, five
+    levels): every instruction of the compiled scan carries an
+    ``event_loop.*`` or ``compaction.*`` scope, or is one of the two kernel
+    custom calls, named by their Pallas entry points."""
+    from test_layer_scopes import check_layers, engine_text
+
+    text = engine_text("pallas", one_chip)
+    assert "tpu_custom_call" in text
+    check_layers(text)
