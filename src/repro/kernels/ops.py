@@ -269,11 +269,11 @@ class CapacityPlan:
 
     @property
     def tile_vmem_bytes(self) -> int:
-        """Working-set bytes of one (BI, BJ) interaction tile: ~12 live
-        per-pair intermediates at the compute width plus the staged blocks
-        at the I/O width (the VMEM budget note in ``nbody_force.py``) —
-        a ``mixed`` plan's tile fits in roughly half the fp32 footprint,
-        which is what lets occupancy rise at fixed VMEM."""
+        """Working-set bytes of one (BI, BJ) interaction tile, an occupancy
+        model: ~12 live per-pair intermediates at the compute width plus
+        the staged blocks at the I/O width — a ``mixed`` plan's tile fits
+        in roughly half the fp32 footprint, which is what lets occupancy
+        rise at fixed VMEM."""
         live = 12 * self.block_i * self.block_j * self.compute_bytes_per_element
         return live + self.tile_io_bytes
 

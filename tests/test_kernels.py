@@ -23,22 +23,81 @@ def _cloud(n, seed=0, dtype=F32):
     return pos, vel, mass
 
 
-@pytest.mark.parametrize("n,block_i,block_j", [
-    (256, 128, 128),
-    (512, 256, 512),
-    (300, 128, 256),     # non-multiple of block => padding path
-    (1024, 256, 512),
-    (128, 8, 128),       # minimal sublane/lane-aligned blocks
-])
-def test_acc_jerk_pot_matches_ref(n, block_i, block_j):
-    pos, vel, mass = _cloud(n)
-    a_k, j_k, p_k = ops.acc_jerk_pot_rect(
-        pos, vel, pos, vel, mass, impl="pallas_interpret",
-        block_i=block_i, block_j=block_j)
-    a_r, j_r, p_r = ref.acc_jerk_pot_rect(pos, vel, pos, vel, mass)
-    np.testing.assert_allclose(a_k, a_r, rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(j_k, j_r, rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(p_k, p_r, rtol=2e-5, atol=2e-5)
+def _members(n, block_i, layout):
+    """Clouds and target masks of a kernel-vs-oracle case.
+
+    ``plain`` is one all-active cloud (no mask); ``masked`` leaves the
+    first i-block wholly inactive and the second partly active; ``vmap``
+    batches an all-active member with a wholly inactive one."""
+    if layout == "vmap":
+        return ([_cloud(n), _cloud(n, seed=5)],
+                [np.ones(n, bool), np.zeros(n, bool)])
+    if layout == "plain":
+        return [_cloud(n)], [None]
+    mask = np.ones(n, bool)
+    mask[:block_i] = False
+    mask[block_i + 1 : 2 * block_i : 3] = False
+    return [_cloud(n)], [mask]
+
+
+def _evaluate(fn, clouds, masks):
+    """``fn(pos, vel, mass, mask)`` per member; one ``jax.vmap``-batched
+    call when the case has more than one member."""
+    if len(clouds) == 1:
+        return [fn(*clouds[0], masks[0])]
+    stacked = [jnp.stack(xs) for xs in zip(*clouds)] + [jnp.stack(masks)]
+    out = jax.vmap(fn)(*stacked)
+    return [jax.tree_util.tree_map(lambda x: x[b], out)
+            for b in range(len(clouds))]
+
+
+def _assert_rows(got, want, mask, tols):
+    """Active rows match the oracle; inactive rows are exact zeros."""
+    for g, w, tol in zip(got, want, tols):
+        g, w = np.asarray(g), np.asarray(w)
+        if mask is not None:
+            assert not g[~mask].any()
+            g, w = g[mask], w[mask]
+        np.testing.assert_allclose(g, w, rtol=tol, atol=tol)
+
+
+# n, block_i, block_j, layout: the source sweep as one block of one or two
+# 128-lane chunks and as 8 or more blocks, block_j below and above 128,
+# and target activity per i-block and per batch member
+KERNEL_CASES = [
+    (256, 128, 128, "plain"),
+    (512, 256, 512, "plain"),
+    (300, 128, 256, "plain"),     # non-multiple of block => padding path
+    (1024, 256, 512, "plain"),
+    (128, 8, 128, "plain"),       # minimal sublane/lane-aligned blocks
+    (256, 64, 256, "plain"),      # one source block of two lane chunks
+    (1024, 64, 32, "plain"),      # 32 source blocks of 32 lanes
+    (1024, 128, 128, "masked"),   # 8 source blocks, i-block 0 inactive
+    (512, 64, 256, "vmap"),       # 2 source blocks, member 1 inactive
+]
+SNAP_CASES = [KERNEL_CASES[i] for i in (0, 2, 1, 5, 6, 7, 8)]
+
+
+def _case_id(case):
+    n, block_i, block_j, layout = case
+    tail = "" if layout == "plain" else f"-{layout}"
+    return f"{n}-{block_i}-{block_j}{tail}"
+
+
+@pytest.mark.parametrize("n,block_i,block_j,layout", KERNEL_CASES,
+                         ids=[_case_id(c) for c in KERNEL_CASES])
+def test_acc_jerk_pot_matches_ref(n, block_i, block_j, layout):
+    clouds, masks = _members(n, block_i, layout)
+
+    def kernel(pos, vel, mass, mask):
+        return ops.acc_jerk_pot_rect(
+            pos, vel, pos, vel, mass, mask_t=mask, impl="pallas_interpret",
+            block_i=block_i, block_j=block_j)
+
+    for (pos, vel, mass), mask, got in zip(
+            clouds, masks, _evaluate(kernel, clouds, masks)):
+        want = ref.acc_jerk_pot_rect(pos, vel, pos, vel, mass)
+        _assert_rows(got, want, mask, (2e-5, 2e-4, 2e-5))
 
 
 @pytest.mark.parametrize("n_t,n_s", [(128, 512), (512, 128), (256, 256)])
@@ -53,17 +112,22 @@ def test_rectangular_contract(n_t, n_s):
     np.testing.assert_allclose(j_k, j_r, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("n,block_i,block_j", [
-    (256, 128, 128), (300, 128, 256), (512, 256, 512),
-])
-def test_snap_matches_ref(n, block_i, block_j):
-    pos, vel, mass = _cloud(n)
-    acc, _, _ = ref.acc_jerk_pot_rect(pos, vel, pos, vel, mass)
-    s_k = ops.snap_rect(pos, vel, acc, pos, vel, acc, mass,
-                        impl="pallas_interpret",
-                        block_i=block_i, block_j=block_j)
-    s_r = ref.snap_rect(pos, vel, acc, pos, vel, acc, mass)
-    np.testing.assert_allclose(s_k, s_r, rtol=5e-4, atol=5e-4)
+@pytest.mark.parametrize("n,block_i,block_j,layout", SNAP_CASES,
+                         ids=[_case_id(c) for c in SNAP_CASES])
+def test_snap_matches_ref(n, block_i, block_j, layout):
+    clouds, masks = _members(n, block_i, layout)
+
+    def kernel(pos, vel, mass, mask):
+        acc, _, _ = ref.acc_jerk_pot_rect(pos, vel, pos, vel, mass)
+        return (ops.snap_rect(pos, vel, acc, pos, vel, acc, mass,
+                              mask_t=mask, impl="pallas_interpret",
+                              block_i=block_i, block_j=block_j),)
+
+    for (pos, vel, mass), mask, got in zip(
+            clouds, masks, _evaluate(kernel, clouds, masks)):
+        acc, _, _ = ref.acc_jerk_pot_rect(pos, vel, pos, vel, mass)
+        want = ref.snap_rect(pos, vel, acc, pos, vel, acc, mass)
+        _assert_rows(got, (want,), mask, (5e-4,))
 
 
 def test_row_chunked_rect_matches_dense(monkeypatch):
