@@ -56,6 +56,8 @@ COMPACTIONS = ("none", "gather")
 #: pass — the p-th round's result is discarded, the dead collective the
 #: overlap schedule eliminates)
 RING_MODES = ("overlap", "sync")
+#: the named scope of the collectives mesh_sharded's specs imply
+IMPLIED_SCOPE = "collective.implied"
 
 
 def make_batch_mesh(
@@ -570,21 +572,23 @@ def _wrap_block(p, eval_padded):
     def evaluate(pos, vel, acc_pred, mass, mask_t, n_bound=None):
         n = pos.shape[0]
         f32 = jnp.float32
-        pos32 = jnp.asarray(pos, f32)
-        vel32 = jnp.asarray(vel, f32)
-        ap32 = jnp.asarray(acc_pred, f32)
-        mass32 = jnp.asarray(mass, f32)
-        mask = jnp.asarray(mask_t, bool)
-        n_pad = _round_up(n, p)
-        pp, vp, mp = _pad_particles(pos32, vel32, mass32, n_pad)
-        app = jnp.pad(ap32, ((0, n_pad - n), (0, 0)))
-        mk = jnp.pad(mask, ((0, n_pad - n),))
+        with jax.named_scope(ops.PACK):
+            pos32 = jnp.asarray(pos, f32)
+            vel32 = jnp.asarray(vel, f32)
+            ap32 = jnp.asarray(acc_pred, f32)
+            mass32 = jnp.asarray(mass, f32)
+            mask = jnp.asarray(mask_t, bool)
+            n_pad = _round_up(n, p)
+            pp, vp, mp = _pad_particles(pos32, vel32, mass32, n_pad)
+            app = jnp.pad(ap32, ((0, n_pad - n), (0, 0)))
+            mk = jnp.pad(mask, ((0, n_pad - n),))
         if n_bound is None:
             bound = jnp.sum(mk.reshape(p, -1), axis=1).astype(jnp.int32)
         else:
             bound = jnp.asarray(n_bound, jnp.int32).reshape(p)
         acc, jerk, snp, pot, tiles = eval_padded(pp, vp, app, mp, mk, bound)
-        return (Evaluation(acc[:n], jerk[:n], snp[:n], pot[:n]), tiles)
+        with jax.named_scope(ops.PACK):
+            return (Evaluation(acc[:n], jerk[:n], snp[:n], pot[:n]), tiles)
 
     return evaluate
 
@@ -713,7 +717,10 @@ def _two_level_block(mesh, order, kw, compaction, n_passes):
 def _mesh_sharded_block(mesh, order, kw, compaction, n_passes):
     """Runtime-managed comms: the kernel regions are shard_mapped with
     *replicated* in_specs for the source operands — the collective is implied
-    by the spec (cf. TT-NN MeshDevice replicated buffers), never written."""
+    by the spec (cf. TT-NN MeshDevice replicated buffers), never written.
+    The partitioner places it at the ``shard_map`` boundary, which is
+    scoped ``collective.implied``; the kernels and the compaction inside
+    keep their own, deeper scopes."""
     axes = mesh.axis_names
     sh, rep = P(axes), P()
 
@@ -744,10 +751,13 @@ def _mesh_sharded_block(mesh, order, kw, compaction, n_passes):
     def eval_padded(pos, vel, ap, mass, mask, bound):
         # targets arrive sharded, sources replicated — the same arrays bound
         # twice with different specs; the runtime inserts the all-gathers
-        acc, jerk, pot, acc_s, tiles = pass1(pos, vel, ap, mask, bound,
-                                             pos, vel, mass)
+        with jax.named_scope(IMPLIED_SCOPE):
+            acc, jerk, pot, acc_s, tiles = pass1(pos, vel, ap, mask, bound,
+                                                 pos, vel, mass)
         if order >= 6:
-            snp = pass2(pos, vel, acc, mask, bound, pos, vel, acc_s, mass)
+            with jax.named_scope(IMPLIED_SCOPE):
+                snp = pass2(pos, vel, acc, mask, bound, pos, vel, acc_s,
+                            mass)
         else:
             snp = jnp.zeros_like(acc)
         return acc, jerk, snp, pot, tiles

@@ -42,6 +42,7 @@ the ``num > 0`` guard) nor on mass-weighted energy diagnostics.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -593,16 +594,24 @@ def _event_init(s, na, t_end, *, eta, dt_max, n_levels: int):
     return t_last, levels, dt_macro
 
 
+def _scope(name: Optional[str]):
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
 # One event is split in three stages so the compaction layer can pick its
 # capacity bucket(s) *between* the per-member vmaps (the ensemble engine) or
 # inside the per-shard switch (the strategy engine).
-def _event_pre(s, t_last, levels, dt_macro, na, t_end, *, n_sub: int):
+def _event_pre(s, t_last, levels, dt_macro, na, t_end, *, n_sub: int,
+               reduce_scope: Optional[str] = None):
+    """``reduce_scope`` names the reduction over rows (the strategy engine
+    shards its rows, so there it is an all-reduce)."""
     dtype = s.pos.dtype
     live = (t_end - s.time) > 0.0
     real = jnp.arange(s.pos.shape[0]) < na
     period = jnp.asarray(n_sub, jnp.int32) >> levels
     cand = t_last + period
-    t_next = jnp.min(jnp.where(real, cand, n_sub))
+    with _scope(reduce_scope):
+        t_next = jnp.min(jnp.where(real, cand, n_sub))
     active = real & (cand == t_next)
     dt_fine = dt_macro / n_sub
     h = ((t_next - t_last).astype(dtype) * dt_fine)[:, None]
@@ -614,7 +623,8 @@ def _event_pre(s, t_last, levels, dt_macro, na, t_end, *, n_sub: int):
 
 def _event_post(s, ev, live, t_next, active, h, t_last, levels,
                 dt_macro, na, t_end, *, n_sub: int, eta, dt_max,
-                n_levels: int, order: int):
+                n_levels: int, order: int,
+                reduce_scope: Optional[str] = None):
     dtype = s.pos.dtype
     period = jnp.asarray(n_sub, jnp.int32) >> levels
     # an active particle last corrected exactly its own step ago, so the
@@ -660,7 +670,11 @@ def _event_post(s, ev, live, t_next, active, h, t_last, levels,
     st1, t_last1, lev1, dt_macro1 = jax.tree_util.tree_map(
         lambda new, old: jnp.where(live, new, old),
         (st1, t_last1, lev1, dt_macro1), (s, t_last, levels, dt_macro))
-    dp = jnp.where(live, jnp.sum(active).astype(dtype) * na, 0.0)
+    # int32 counts: a 64-bit sum over sharded rows becomes an unscoped
+    # all-reduce of 32-bit halves on the TPU
+    with _scope(reduce_scope):
+        n_active = jnp.sum(active, dtype=jnp.int32)
+    dp = jnp.where(live, n_active.astype(dtype) * na, 0.0)
     return st1, t_last1, lev1, dt_macro1, dp, live
 
 
@@ -1486,8 +1500,40 @@ def evolve_ensemble_block(
 # --------------------------------------------------------------------------
 # single-run block stepper under a multi-device distribution strategy
 # --------------------------------------------------------------------------
+#: the strategy engine's row axis: its run's rows are sharded over it
+ROW_AXIS = "dev"
+#: the strategy engine's reductions over its sharded rows
+ROWS_REDUCE_SCOPE = "collective.all_reduce"
+
+
 @functools.lru_cache(maxsize=64)
-def _strategy_block_engine(strategy: str, n_devices: int,
+def _strategy_layout(devices: tuple, n: int):
+    """``(state, carry)`` shardings in which the strategy block engine keeps
+    one run between its calls, on a 1-D mesh over ``devices``.
+
+    Rows are sharded: each device predicts and corrects only its own
+    contiguous N/P rows in float64, which are exactly the targets its
+    force shard evaluates, and the evaluator's replicated source specs
+    gather the predicted rows (the collectives of the paper's strategies).
+    Replicated rows would put all N rows' float64 event-loop work on every
+    device.  ``mass`` and ``time`` are replicated (the mass is a source
+    operand of every pass and never changes), as is every counter but
+    ``n_tiles``, whose entry ``k`` is shard ``k``'s.  When N does not
+    divide over the devices the rows are replicated too."""
+    mesh = make_batch_mesh(devices, axis_name=ROW_AXIS)
+    rep = NamedSharding(mesh, P())
+    per_device = NamedSharding(mesh, P(ROW_AXIS))
+    rows = per_device if n % len(devices) == 0 else rep
+    state = ParticleState(pos=rows, vel=rows, acc=rows, jerk=rows,
+                          snap=rows, crackle=rows, mass=rep, pot=rows,
+                          time=rep)
+    carry = BlockCarry(t_last=rows, levels=rows, dt_macro=rep, n_pairs=rep,
+                       n_events=rep, n_tiles=per_device, bucket_hits=rep)
+    return state, carry
+
+
+@functools.lru_cache(maxsize=64)
+def _strategy_block_engine(strategy: str, devices: tuple,
                            chips_per_card: int, order: int, eps: float,
                            impl: str, eta: float, dt_max: float,
                            n_levels: int, compaction: str,
@@ -1512,22 +1558,32 @@ def _strategy_block_engine(strategy: str, n_devices: int,
     so the tick's active set IS ``{level >= threshold}`` and the bound
     equals the measured count — identical buckets, tiles, and physics
     (``test_obs_metrics.py`` pins ``launched <= bound-sized <= dense``).
+
+    Both programs return the run in :func:`_strategy_layout`, the layout
+    :func:`strategy_run_block` places it in, so each chunk length compiles
+    once.
     """
     from repro.core.strategies import make_strategy_block_evaluator
 
     _count_engine_build("block_strategy")
-    devs = jax.devices()[:n_devices]
+    n_devices = len(devices)
     bev = make_strategy_block_evaluator(
-        strategy, devices=devs, chips_per_card=chips_per_card, eps=eps,
+        strategy, devices=devices, chips_per_card=chips_per_card, eps=eps,
         order=order, impl=impl, block_i=block_i, block_j=block_j,
         compaction=compaction, dtype=dtype, sources=sources)
     n_sub = 2 ** (n_levels - 1)
     event_init = functools.partial(_event_init, eta=eta, dt_max=dt_max,
                                    n_levels=n_levels)
-    event_pre = functools.partial(_event_pre, n_sub=n_sub)
+    event_pre = functools.partial(_event_pre, n_sub=n_sub,
+                                  reduce_scope=ROWS_REDUCE_SCOPE)
     event_post = functools.partial(_event_post, n_sub=n_sub, eta=eta,
                                    dt_max=dt_max, n_levels=n_levels,
-                                   order=order)
+                                   order=order,
+                                   reduce_scope=ROWS_REDUCE_SCOPE)
+
+    def placed(state, carry):
+        layout = _strategy_layout(devices, state.pos.shape[0])
+        return jax.lax.with_sharding_constraint((state, carry), layout)
 
     @functools.partial(jax.jit, static_argnames=("n_events",))
     def run(state, carry: BlockCarry, t_end, n_events: int):
@@ -1571,14 +1627,14 @@ def _strategy_block_engine(strategy: str, n_devices: int,
         with jax.named_scope("event_loop.carry"):
             (state, carry), _ = jax.lax.scan(body, (state, carry), None,
                                              length=n_events)
-        return state, carry
+            return placed(state, carry)
 
     @jax.jit
     def init(state, t_end):
         t_last, levels, dt_macro = event_init(state, state.pos.shape[0],
                                               t_end)
         count_dtype = jax.dtypes.canonicalize_dtype(jnp.float64)
-        return BlockCarry(
+        carry = BlockCarry(
             t_last=t_last, levels=levels, dt_macro=dt_macro,
             n_pairs=jnp.zeros((), count_dtype),
             n_events=jnp.zeros((), jnp.int32),
@@ -1586,16 +1642,19 @@ def _strategy_block_engine(strategy: str, n_devices: int,
             # the per-shard switch lives inside the shards; no batch-level
             # bucket distribution to report (see grid_tiles_per_shard)
             bucket_hits=jnp.zeros((0,), count_dtype))
+        return placed(state, carry)[1]
 
     return init, run
 
 
-def _n_devices(devices) -> int:
+def _devices(devices) -> tuple:
+    """``devices`` (an int count, a device sequence, or None for all
+    visible devices) as a device tuple."""
     if devices is None:
-        return len(jax.devices())
+        return tuple(jax.devices())
     if isinstance(devices, int):
-        return devices
-    return len(list(devices))
+        return tuple(jax.devices()[:devices])
+    return tuple(devices)
 
 
 def strategy_run_block(
@@ -1635,14 +1694,18 @@ def strategy_run_block(
     """
     if n_levels < 1:
         raise ValueError(f"n_levels={n_levels} must be >= 1")
+    devs = _devices(devices)
     init, run = _strategy_block_engine(
-        strategy, _n_devices(devices), chips_per_card, order, eps, impl,
+        strategy, devs, chips_per_card, order, eps, impl,
         eta, dt_max, n_levels, compaction,
         block_i or nbody_force.DEFAULT_BLOCK_I,
         block_j or nbody_force.DEFAULT_BLOCK_J, dtype, sources)
     t_end_ = jnp.asarray(t_end, state.pos.dtype)
-    if carry is None:
-        carry = init(state, t_end_)
+    # the engine's own layout from the first call on (a no-op after it)
+    state_layout, carry_layout = _strategy_layout(devs, state.pos.shape[0])
+    state = jax.device_put(state, state_layout)
+    carry = (init(state, t_end_) if carry is None
+             else jax.device_put(carry, carry_layout))
     return run(state, carry, t_end_, n_events)
 
 
@@ -1673,9 +1736,9 @@ def evolve_strategy_block(
     from repro.core.strategies import make_strategy_evaluator
 
     impl = resolve_eval_impl(impl, kernel)
-    ndev = _n_devices(devices)
+    devs = _devices(devices)
     ev = make_strategy_evaluator(
-        strategy, devices=jax.devices()[:ndev],
+        strategy, devices=devs,
         chips_per_card=chips_per_card, eps=eps, order=order, impl=impl,
         block_i=block_i or nbody_force.DEFAULT_BLOCK_I,
         block_j=block_j or nbody_force.DEFAULT_BLOCK_J, dtype=dtype)
@@ -1688,7 +1751,7 @@ def evolve_strategy_block(
             impl=impl, dtype=dtype, strategy=strategy,
             chips_per_card=chips_per_card,
             compaction=compaction, block_i=block_i, block_j=block_j,
-            devices=ndev)
+            devices=devs)
         if float(state.time) >= t_end:
             break
     return state, carry

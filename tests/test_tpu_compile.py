@@ -182,3 +182,19 @@ def test_block_engine_layer_scopes_reach_the_tpu_program(
     text = engine_text("pallas", one_chip)
     assert "tpu_custom_call" in text
     check_layers(text)
+
+
+def test_mesh_sharded_block_engine_compiles_at_the_cell_size(
+        topo, no_persistent_cache):
+    """The four-chip cell's event-loop program: N = 131072 domain-decomposed
+    over the four described chips by ``mesh_sharded``, 256 x 512 tiles,
+    eight levels, gather compaction, 126 events.  Every instruction of its
+    scan has its layer, and every collective the partitioner inserts a
+    ``collective.*`` scope."""
+    from test_layer_scopes import check_strategy_layers, strategy_engine_text
+
+    text = strategy_engine_text(
+        "pallas", topo.devices, n=131072, block_i=nbody_force.DEFAULT_BLOCK_I,
+        block_j=nbody_force.DEFAULT_BLOCK_J, levels=8, events=126)
+    assert "tpu_custom_call" in text and "num_partitions=4" in text
+    check_strategy_layers(text)
