@@ -370,6 +370,38 @@ def get_runner(kind: str) -> Runner:
             f"registered: {tuple(RUNNERS)}") from None
 
 
+def _single_engine(evaluator, order: int, devices: tuple = ()):
+    """A lockstep run's whole P-E-C step as one compiled program
+    ``advance(state, dt)``, and its shared Aarseth step
+    ``shared_dt(state, eta, dt_max)``.
+
+    ``build`` makes them once per run (``engine.cache_miss.single`` reads
+    1 per run), so each run traces the integrator and kernels as they are
+    when it is built.  ``dt``, ``eta`` and ``dt_max`` are traced operands:
+    a clamped last step or an adaptive dt compiles nothing new.  Under a
+    distribution strategy over ``devices`` the step keeps the state in the
+    strategy engine's row layout (:func:`ens._strategy_layout`), where
+    ``build`` puts it, so its second call meets the layout of its first.
+    The state is not donated: callers may hold the state a step started
+    from.
+    """
+    ens._count_engine_build("single")
+
+    @jax.jit
+    def advance(state, dt):
+        out = hermite.step(state, dt, evaluator, order=order)
+        if devices:
+            layout = ens._strategy_layout(devices, out.pos.shape[0])[0]
+            out = jax.lax.with_sharding_constraint(out, layout)
+        return out
+
+    @jax.jit
+    def shared_dt(state, eta, dt_max):
+        return hermite.aarseth_dt(state, eta=eta, dt_max=dt_max)
+
+    return advance, shared_dt
+
+
 # --------------------------------------------------------------------------
 # single run (per-step telemetry, any strategy, adaptive or fixed dt)
 # --------------------------------------------------------------------------
@@ -387,6 +419,7 @@ class SingleRunner(Runner):
         # None lets make_evaluator pick the backend default; an explicit
         # impl+kernel pair is a conflict (e.g. fp64 vs a kernel switch)
         impl = ens.resolve_eval_impl(cfg.impl, cfg.kernel, default=None)
+        devices = ()
         if cfg.strategy == "single":
             if impl == "fp64" or cfg.dtype == "fp64":
                 # golden reference: a precision, not a kernel
@@ -403,18 +436,24 @@ class SingleRunner(Runner):
                     "fp64 (golden reference) only runs under "
                     "strategy='single'")
             impl = impl or "xla"
+            devices = tuple(_device_list(cfg))
             evaluator = make_strategy_evaluator(
-                cfg.strategy, devices=_device_list(cfg), order=cfg.order,
+                cfg.strategy, devices=devices, order=cfg.order,
                 eps=cfg.eps, impl=impl, dtype=cfg.dtype)
         else:
             raise ValueError(f"unknown strategy {cfg.strategy!r}")
+        h.advance, h.shared_dt = _single_engine(evaluator, cfg.order,
+                                                devices)
 
         h.recorder = telemetry.TelemetryRecorder({**cfg.meta(), "impl": impl})
         state = hermite.initialize(state, evaluator)
+        if devices:
+            state = jax.device_put(
+                state, ens._strategy_layout(devices, cfg.n)[0])
         jax.block_until_ready(state.pos)
         h.e0 = float(nbody.total_energy(state))
         h.recorder.record_snapshot(0, 0.0, energy=h.e0, de_rel=0.0)
-        h.state, h.evaluator = state, evaluator
+        h.state = state
         h.steps, h.h_prev, h.t = 0, None, float(state.time)
         h.finished = not h.t < cfg.t_end
         return h
@@ -429,15 +468,13 @@ class SingleRunner(Runner):
                 if cfg.dt is not None:
                     dt = cfg.dt
                 else:
-                    dt = float(hermite.aarseth_dt(h.state, eta=cfg.eta,
-                                                  dt_max=cfg.dt_max))
+                    dt = float(h.shared_dt(h.state, cfg.eta, cfg.dt_max))
                     if h.h_prev is not None:  # rate-limit dt changes
                         dt = min(max(dt, 0.5 * h.h_prev), 2.0 * h.h_prev)
                     h.h_prev = dt
                 dt = min(dt, cfg.t_end - h.t)
                 t0 = time.perf_counter()
-                state = hermite.step(h.state, jnp.asarray(dt, h.state.dtype),
-                                     h.evaluator, order=cfg.order)
+                state = h.advance(h.state, jnp.asarray(dt, h.state.dtype))
                 jax.block_until_ready(state.pos)
             h.state = state
             h.steps += 1

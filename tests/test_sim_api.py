@@ -3,9 +3,17 @@ build/step/collect dispatch, the typed ``ScenarioSpec`` request surface and
 the versioned ``RunReport`` — plus the ``driver`` compatibility shim."""
 
 import json
+import os
+import subprocess
+import sys
 
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from repro.core import hermite
+from repro.core.evaluate import make_evaluator
+from repro.obs import metrics as obs_metrics
 from repro.sim import api, driver, scenarios, telemetry
 from repro.sim.scenarios import ScenarioError, ScenarioSpec
 from repro.sim.telemetry import REPORT_SCHEMA_VERSION, RunReport
@@ -84,6 +92,149 @@ def test_run_twice_is_deterministic():
     cfg = _cfg()
     a, b = api.run(cfg), api.run(cfg)
     assert _deterministic(a) == _deterministic(b)
+
+
+# --------------------------------------------------------------------------
+# the lockstep runner's step: one compiled program
+# --------------------------------------------------------------------------
+_STATE_FIELDS = ("pos", "vel", "acc", "jerk", "snap", "crackle", "pot",
+                 "time")
+
+
+def _lockstep_cfg(stepper, **kw):
+    dt = 1.0 / 256 if stepper == "fixed" else None
+    return _cfg(**{"n": 256, "impl": "xla", "stepper": stepper, "dt": dt,
+                   "dt_max": 1.0 / 64, **kw})
+
+
+@pytest.mark.parametrize("stepper", ["fixed", "adaptive"])
+@pytest.mark.parametrize("order", [4, 6])
+def test_single_runner_matches_eager_hermite(order, stepper):
+    """The compiled step reproduces an eager loop of ``hermite.step`` with
+    the same evaluator, dt choice and clamp, step by step."""
+    cfg = _lockstep_cfg(stepper, order=order, t_end=6.5 / 256)
+    runner = api.get_runner("single")
+    h = runner.build(cfg)
+    ev = make_evaluator(order=order, eps=cfg.eps, impl="xla",
+                        dtype=cfg.dtype)
+    state, t, h_prev = h.state, float(h.state.time), None
+    while not h.finished:
+        runner.step(h)
+        if stepper == "fixed":
+            dt = cfg.dt
+        else:
+            dt = float(hermite.aarseth_dt(state, eta=cfg.eta,
+                                          dt_max=cfg.dt_max))
+            if h_prev is not None:
+                dt = min(max(dt, 0.5 * h_prev), 2.0 * h_prev)
+            h_prev = dt
+        dt = min(dt, cfg.t_end - t)
+        state = hermite.step(state, jnp.asarray(dt, state.dtype), ev,
+                             order=order)
+        t = float(state.time)
+        for k in _STATE_FIELDS:
+            ref = np.asarray(getattr(state, k))
+            got = np.asarray(getattr(h.state, k))
+            if k == "crackle":
+                # the crackle is a difference of accelerations over h**3:
+                # compare it as it enters the next prediction, in units
+                # of the acceleration (c h**3 / max|a|), where float64
+                # rounding of the cancelling difference shows at ~1e-16
+                unit = dt ** 3 / float(np.max(np.abs(state.acc)))
+                ref, got = ref * unit, got * unit
+            np.testing.assert_allclose(
+                got, ref, rtol=0, atol=1e-12,
+                err_msg=f"{k} at step {h.steps}")
+    assert h.steps >= 2
+
+
+@pytest.mark.parametrize("stepper", ["fixed", "adaptive"])
+def test_single_runner_builds_its_step_once(stepper):
+    """Ten steps, the last clamped to ``t_end``: one engine build and one
+    compiled program each for the step and the shared dt."""
+    # below every Aarseth step of this cluster, so adaptive steps dt_max too
+    dt = 1.0 / 8192
+    cfg = _lockstep_cfg(stepper, t_end=9.5 * dt, dt_max=dt,
+                        dt=dt if stepper == "fixed" else None)
+    runner = api.get_runner("single")
+    with obs_metrics.use() as reg:
+        h = runner.build(cfg)
+        while not runner.step(h):
+            pass
+        counters = reg.snapshot()["counters"]
+    assert h.steps == 10
+    assert float(h.state.time) == cfg.t_end
+    assert counters["engine.cache_miss.single"]["value"] == 1
+    assert h.advance._cache_size() == 1
+    assert h.shared_dt._cache_size() == (stepper == "adaptive")
+
+
+@pytest.mark.parametrize("stepper", ["fixed", "adaptive"])
+def test_single_runner_keeps_held_state(stepper):
+    """The step donates nothing: a state held from before a step reads
+    back unchanged after it."""
+    runner = api.get_runner("single")
+    h = runner.build(_lockstep_cfg(stepper))
+    held = h.state
+    before = {k: np.array(getattr(held, k)) for k in _STATE_FIELDS}
+    runner.step(h)
+    runner.step(h)
+    assert h.state is not held
+    for k in _STATE_FIELDS:
+        np.testing.assert_array_equal(np.asarray(getattr(held, k)),
+                                      before[k])
+
+
+_STRATEGY_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax
+jax.config.update("jax_enable_x64", True)
+import jax.numpy as jnp
+import numpy as np
+from repro.core import hermite
+from repro.core.strategies import make_strategy_evaluator
+from repro.obs import metrics as obs_metrics
+from repro.sim import api
+
+for strategy in ("replicated", "mesh_sharded", "ring", "two_level"):
+    cfg = api.SimConfig(scenario="plummer", n=256, t_end=5 / 256,
+                        dt=1 / 256, strategy=strategy, devices=2,
+                        impl="xla", validate_ic=False)
+    runner = api.get_runner("single")
+    with obs_metrics.use() as reg:
+        h = runner.build(cfg)
+        ev = make_strategy_evaluator(strategy, devices=jax.devices(),
+                                     impl="xla")
+        state = h.state
+        while not h.finished:
+            runner.step(h)
+            state = hermite.step(state, jnp.asarray(cfg.dt), ev)
+            for k in ("pos", "vel", "acc", "jerk", "snap", "pot"):
+                np.testing.assert_allclose(
+                    np.asarray(getattr(h.state, k)),
+                    np.asarray(getattr(state, k)), rtol=0, atol=1e-12,
+                    err_msg=f"{strategy} {k}")
+        builds = reg.snapshot()["counters"]["engine.cache_miss.single"]
+    assert h.steps == 5, (strategy, h.steps)
+    assert builds["value"] == 1, (strategy, builds)
+    assert h.advance._cache_size() == 1, (strategy, h.advance._cache_size())
+    print(f"{strategy}: OK")
+"""
+
+
+def test_single_runner_strategies_compile_once_2dev():
+    """Under every distribution strategy on two devices the compiled step
+    matches an eager loop of ``hermite.step`` with the strategy's
+    evaluator, and keeps its layout: one program for all five steps."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    res = subprocess.run([sys.executable, "-c", _STRATEGY_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stdout + "\n" + res.stderr
+    for strategy in ("replicated", "mesh_sharded", "ring", "two_level"):
+        assert f"{strategy}: OK" in res.stdout
 
 
 # --------------------------------------------------------------------------

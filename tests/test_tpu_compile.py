@@ -20,10 +20,11 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import hermite, nbody
+from repro.core import nbody
 from repro.core.evaluate import make_evaluator
 from repro.core.strategies import make_strategy_evaluator
 from repro.kernels import nbody_force, ops
+from repro.sim import api
 from repro.sim import ensemble as ens
 
 N = 65536
@@ -88,11 +89,12 @@ def _state_shapes(n, sharding):
 
 
 def test_hermite_step_f64_state_compiles(one_chip, no_persistent_cache):
-    """One 6th-order step: f64 state around the fp32 Pallas evaluation."""
-    ev = make_evaluator(impl="pallas")
+    """One 6th-order step: f64 state around the fp32 Pallas evaluation, as
+    the lockstep runner's one compiled program."""
+    advance, _ = api._single_engine(make_evaluator(impl="pallas"), 6)
     state = _state_shapes(N, one_chip)
-    text = _compiled_text(lambda s, dt: hermite.step(s, dt, ev), state,
-                          _sds((), jnp.float64, one_chip))
+    text = advance.lower(state, _sds((), jnp.float64, one_chip)
+                         ).compile().as_text()
     assert "tpu_custom_call" in text
 
 
