@@ -18,10 +18,10 @@ One chip, three phases in one process, all on the compiled Pallas kernels:
     engine rebuilt and no XLA compile after ``warmup``.
 
 ``--four-chip`` runs, on four chips, the four strategies as N = 65536 block
-runs (``BlockStrategyRunner``), a few fixed-dt lockstep steps of each strategy
-(``SingleRunner``) and the fused ``mesh=(2, 2)`` ensemble of 2 members, each
-against the same seed on one chip in this process, on the forces at t = 0 and
-on |dE/E| (within ``DE_MATCH`` of the one-chip value).
+runs (``BlockStrategyRunner``) and a few fixed-dt lockstep steps of each
+strategy (``SingleRunner``), each against the same seed on one chip in this
+process, on the forces at t = 0 and on |dE/E| (within ``DE_MATCH`` of the
+one-chip value).
 
 Each phase prints one line of its numbers.  The last line of standard output
 is ``{"ok": true, "device": {...}}``, printed only when every check passed on
@@ -61,7 +61,6 @@ N_LEVELS = 10
 FOUR_CHIP_LEVELS = 9
 SERVER_N = (4096, 4096, 8192, 8192)
 LOCKSTEP_DT, LOCKSTEP_STEPS = 1.0 / 1024, 3
-FUSED_N = 16384
 #: a four-chip run must end at the one-chip |dE/E| within this.  The layouts
 #: differ only in the order of the fp32 force sums (bitwise equal at t = 0
 #: except ring, 6e-7 normwise), which moves |dE/E| over a few hundred events
@@ -359,10 +358,6 @@ def four_chip_phase(jax, impl, kind):
     for s in STRATEGIES:
         compare(f"lockstep/{s}", ref,
                 api.SimConfig(**lock, strategy=s, devices=4))
-    fused = dict(block, n=FUSED_N, ensemble=2)
-    compare("fused/mesh2x2",
-            reference("fused/1chip", api.SimConfig(**fused)),
-            api.SimConfig(**fused, mesh=(2, 2), devices=4))
 
 
 def main(argv=None) -> int:

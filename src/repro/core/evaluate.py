@@ -40,10 +40,9 @@ def shared_cap_index(plan: ops.CapacityPlan, bounds) -> jax.Array:
 
     ``lax.switch`` must see an *unbatched* operand under ``jax.vmap`` to
     stay a real branch (a batched index degrades to an execute-all-branches
-    select), so every caller that dispatches one switch for many members —
-    the ensemble engine's bucket groups, the fused ``(batch, dev)``
-    evaluator's per-shard switch — shares the max of the members'
-    active-count ``bounds`` (any shape; flattened).  Sound because a shared
+    select), so the ensemble engine's bucket groups, which dispatch one
+    switch for many members, share the max of the members' active-count
+    ``bounds`` (any shape; flattened).  Sound because a shared
     cap bounds every member's own count: gathered window rows past a
     member's active set are mask-zeroed by the kernels, so the scattered
     result is bit-for-bit the per-member bucket's — only the launch grid

@@ -74,31 +74,6 @@ def make_batch_mesh(
     return Mesh(devs.reshape(devs.size), (axis_name,))
 
 
-def make_fused_mesh(
-    devices: Optional[Sequence[jax.Device]] = None,
-    *,
-    mesh_shape: Sequence[int],
-    axis_names: Sequence[str] = ("batch", "dev"),
-) -> Mesh:
-    """2-D ``(batch, dev)`` mesh fusing ensemble and domain parallelism.
-
-    ``mesh_shape`` is ``(B_shards, P_shards)``: the batch axis of stacked
-    runs is sharded ``B_shards``-way and each run's particle domain
-    ``P_shards``-way, so one ``shard_map`` drives B members x P domain
-    shards at once (:func:`make_fused_block_evaluator`).  The device count
-    must equal ``B_shards * P_shards`` exactly — a silent remainder would
-    drop devices from the fused launch.
-    """
-    devs = np.asarray(list(devices) if devices is not None else jax.devices())
-    bdev, p = (int(x) for x in mesh_shape)
-    if bdev < 1 or p < 1:
-        raise ValueError(f"mesh_shape extents must be >= 1; got {mesh_shape}")
-    if bdev * p != devs.size:
-        raise ValueError(
-            f"mesh_shape {bdev}x{p} needs {bdev * p} devices; got {devs.size}")
-    return Mesh(devs.reshape(bdev, p), tuple(axis_names))
-
-
 def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
@@ -863,162 +838,3 @@ def _ring_block(mesh, order, kw, compaction, n_passes,
         return acc, jerk, snp, pot, tiles
 
     return _wrap_block(mesh.size, eval_padded)
-
-
-# --------------------------------------------------------------------------
-# fused (batch, dev) block evaluator: B ensemble members x P domain shards
-# --------------------------------------------------------------------------
-def _wrap_fused_block(bdev, p, eval_padded):
-    """Pad each member's N to a shard multiple, evaluate, slice back.
-
-    The *batch* axis is the engine's to pad (``sim.ensemble._pad_batch``
-    repeats the first run) — a non-multiple batch here is a caller bug, not
-    something to paper over with silently duplicated physics."""
-
-    def evaluate(pos, vel, acc_pred, mass, mask_t, n_bound=None):
-        b, n = pos.shape[0], pos.shape[1]
-        if b % bdev:
-            raise ValueError(
-                f"batch size {b} not divisible by the mesh's batch extent "
-                f"{bdev}; pad the batch first (sim.ensemble._pad_batch)")
-        f32 = jnp.float32
-        dn = _round_up(n, p) - n
-        pp = jnp.pad(jnp.asarray(pos, f32), ((0, 0), (0, dn), (0, 0)))
-        vp = jnp.pad(jnp.asarray(vel, f32), ((0, 0), (0, dn), (0, 0)))
-        app = jnp.pad(jnp.asarray(acc_pred, f32), ((0, 0), (0, dn), (0, 0)))
-        mp = jnp.pad(jnp.asarray(mass, f32), ((0, 0), (0, dn)))
-        mk = jnp.pad(jnp.asarray(mask_t, bool), ((0, 0), (0, dn)))
-        if n_bound is None:
-            bound = jnp.sum(mk.reshape(b, p, -1), axis=2).astype(jnp.int32)
-        else:
-            bound = jnp.asarray(n_bound, jnp.int32).reshape(b, p)
-        acc, jerk, snp, pot, tiles = eval_padded(pp, vp, app, mp, mk, bound)
-        return (Evaluation(acc[:, :n], jerk[:, :n], snp[:, :n], pot[:, :n]),
-                tiles)
-
-    return evaluate
-
-
-def _fused_block(mesh, order, kw, compaction, n_passes):
-    """One shard_map over the fused mesh: each device holds ``B/bdev``
-    members x ``N/p`` target rows and vmaps the per-shard two-pass block
-    evaluation (:func:`_shard_pass1` / :func:`_shard_pass2`) over its local
-    members.  Sources bind with dev-replicated specs (mesh_sharded style:
-    the same arrays bound twice, GSPMD inserts the along-``dev`` gathers,
-    never across ``batch`` — members stay independent).  The capacity
-    switch is shared across a shard's local members via
-    :func:`repro.core.evaluate.shared_cap_index`, keeping it a real branch
-    under the member vmap."""
-    from repro.core.evaluate import shared_cap_index
-
-    bdev, p = mesh.devices.shape
-    tsh3, tsh2 = P("batch", "dev", None), P("batch", "dev")
-    ssh3, ssh2 = P("batch", None, None), P("batch", None)
-
-    def vperm(mask):
-        return jax.vmap(ops.active_first)(mask)
-
-    @jax.shard_map(mesh=mesh,
-                   in_specs=(tsh3, tsh3, tsh3, tsh2, tsh2, ssh3, ssh3, ssh2),
-                   out_specs=(tsh3, tsh3, tsh2, tsh3, tsh2), check_vma=False)
-    def pass1(pos, vel, ap, mask, bound, gp, gv, gm):
-        b_loc = pos.shape[0]
-        plan = _shard_plan(pos.shape[1], gp.shape[1], kw, n_passes)
-        if compaction == "gather":
-            cap_idx = shared_cap_index(plan, bound)
-            acc, jerk, pot, acc_s = jax.vmap(
-                lambda po, ve, a, mk, pe, sp, sv, sm: _shard_pass1(
-                    po, ve, a, mk, pe, cap_idx, plan, kw, (sp, sv, sm),
-                    order)
-            )(pos, vel, ap, mask, vperm(mask), gp, gv, gm)
-            tiles = jnp.broadcast_to(
-                jnp.reshape(plan.tiles(cap_idx), (1, 1)), (b_loc, 1))
-        else:
-            acc, jerk, pot, acc_s = jax.vmap(
-                lambda po, ve, a, mk, sp, sv, sm: _dense_pass1(
-                    po, ve, a, mk, kw, (sp, sv, sm), order)
-            )(pos, vel, ap, mask, gp, gv, gm)
-            tiles = jnp.full((b_loc, 1), plan.dense_tiles, jnp.int32)
-        return acc, jerk, pot, acc_s, tiles
-
-    @jax.shard_map(mesh=mesh,
-                   in_specs=(tsh3, tsh3, tsh3, tsh2, tsh2, ssh3, ssh3, ssh3,
-                             ssh2),
-                   out_specs=tsh3, check_vma=False)
-    def pass2(pos, vel, acc, mask, bound, gp, gv, ga, gm):
-        plan = _shard_plan(pos.shape[1], gp.shape[1], kw, n_passes)
-        if compaction == "gather":
-            # same shared bucket as pass 1: neither masks nor bounds moved
-            cap_idx = shared_cap_index(plan, bound)
-            return jax.vmap(
-                lambda po, ve, a, mk, pe, sp, sv, sa, sm: _shard_pass2(
-                    po, ve, a, mk, pe, cap_idx, plan, kw, (sp, sv, sm), sa)
-            )(pos, vel, acc, mask, vperm(mask), gp, gv, ga, gm)
-        return jax.vmap(
-            lambda po, ve, a, mk, sp, sv, sa, sm: ops.snap_rect(
-                po, ve, a, sp, sv, sa, sm, mask_t=mk, **kw)
-        )(pos, vel, acc, mask, gp, gv, ga, gm)
-
-    @jax.jit
-    def eval_padded(pos, vel, ap, mass, mask, bound):
-        acc, jerk, pot, acc_s, tiles = pass1(pos, vel, ap, mask, bound,
-                                             pos, vel, mass)
-        if order >= 6:
-            snp = pass2(pos, vel, acc, mask, bound, pos, vel, acc_s, mass)
-        else:
-            snp = jnp.zeros_like(acc)
-        return acc, jerk, snp, pot, tiles
-
-    return _wrap_fused_block(bdev, p, eval_padded)
-
-
-def make_fused_block_evaluator(
-    mesh_shape: Sequence[int],
-    *,
-    devices: Optional[Sequence[jax.Device]] = None,
-    eps: float = 1e-7,
-    order: int = 6,
-    impl: str = "xla",
-    block_i: int = nbody_force.DEFAULT_BLOCK_I,
-    block_j: int = nbody_force.DEFAULT_BLOCK_J,
-    compaction: str = "none",
-    dtype: str = "fp32",
-):
-    """Batched active-target evaluator over a fused ``(batch, dev)`` mesh.
-
-    One ``shard_map`` runs ``B`` ensemble members x ``P`` domain shards at
-    once (``mesh_shape = (B_shards, P_shards)``; see :func:`make_fused_mesh`)
-    — the 2-D composition of the ensemble engine's batch sharding with the
-    ``mesh_sharded`` strategy's domain decomposition, which is what lets a
-    serving pod hold several large-N members on one device group.
-
-    Signature of the returned callable::
-
-        evaluate(pos, vel, acc_pred, mass, mask_t, n_bound=None) \
-            -> (Evaluation, tiles)
-
-    All target operands carry a leading ``(B,)`` batch axis; ``n_bound``,
-    when given, is a ``(B, P)`` host-side upper bound on each member's
-    per-shard active-target count (the analytic
-    ``hermite.block_level_occupancy`` bound — host-side tile scheduling,
-    no runtime gather feeds the bucket switch), and ``None`` falls back to
-    the measured per-member per-shard mask sum.  ``tiles`` is the ``(B, P)``
-    matrix of kernel grid tiles each member enqueued on each domain shard
-    (both Hermite passes).
-
-    Bit-for-bit: each target row is a row-local reduction over the full
-    source set in source order, whatever shard or member-vmap lane it
-    occupies, so the result equals both the 1-D batch-sharded ensemble
-    evaluation and the 1-D ``mesh_sharded`` strategy evaluation of the
-    same member (the fused golden pins all three).  ``compaction="gather"``
-    shares one capacity bucket across a shard's local members
-    (:func:`repro.core.evaluate.shared_cap_index`) — identical physics,
-    the launch grid just follows the widest local member.
-    """
-    if compaction not in COMPACTIONS:
-        raise ValueError(
-            f"compaction must be one of {COMPACTIONS}; got {compaction!r}")
-    mesh = make_fused_mesh(devices, mesh_shape=mesh_shape)
-    kw = _force_kw(impl, block_i, block_j, eps, dtype)
-    n_passes = 2 if order >= 6 else 1
-    return _fused_block(mesh, order, kw, compaction, n_passes)

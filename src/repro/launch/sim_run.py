@@ -26,14 +26,11 @@ below ``steps * N**2`` on scenarios with a wide timestep dynamic range.
 ``--compaction gather`` additionally gathers each event's active targets
 into a dense block-aligned buffer so the kernel grid *shrinks* to the live
 block instead of masking it — telemetry then shows ``grid_tiles`` falling
-with the active set (``--block-i/--block-j`` tune the tile shape).
-``--bucket-mode member`` (the default) dispatches a mixed batch's capacity
-buckets per member group instead of batch-shared, and
+with the active set (``--block-i/--block-j`` tune the tile shape); a
+mixed batch's capacity buckets are dispatched per member group.
 ``--strategy X --devices k --stepper block`` shards a single run's domain
 so every device compacts its *local* active targets (the report then
-carries ``grid_tiles_per_shard``).  ``--mesh BxP`` fuses both axes: one
-shard_map advances B batch shards x P domain shards at once (B*P =
-``--devices``), bit-identical to either 1-D layout.
+carries ``grid_tiles_per_shard``).
 
 Each invocation emits a one-line summary plus a JSON telemetry report
 (wall time, steps/s, interactions/s, modeled energy/EDP, per-run energy
@@ -118,13 +115,6 @@ def main(argv=None):
                          "the masked result, far fewer tiles enqueued); "
                          "with --strategy X --devices k every shard gathers "
                          "its own LOCAL active targets")
-    ap.add_argument("--bucket-mode", default="member",
-                    choices=("member", "shared"),
-                    help="capacity-bucket dispatch under --compaction "
-                         "gather: 'member' groups ensemble members by their "
-                         "n_active ceiling (a mixed batch's quiescent "
-                         "members stop paying the widest member's grid), "
-                         "'shared' is the batch-shared-bucket baseline")
     ap.add_argument("--block-i", type=int, default=None,
                     help="kernel target-tile rows (block stepper; default: "
                          "kernel's own — small N wants a smaller tile so "
@@ -152,13 +142,6 @@ def main(argv=None):
                     choices=("single", "replicated", "two_level",
                              "mesh_sharded", "ring"))
     ap.add_argument("--devices", type=int, default=1)
-    ap.add_argument("--mesh", default=None, metavar="BxP",
-                    help="fused 2-D device grid for the block stepper: "
-                         "B batch shards x P domain shards in ONE "
-                         "shard_map (e.g. --mesh 2x2 with --devices 4). "
-                         "B*P must equal --devices; composes batch "
-                         "sharding with mesh_sharded domain decomposition "
-                         "bit-for-bit (see docs/ensembles.md)")
     ap.add_argument("--impl", default=None,
                     choices=(None, "pallas", "pallas_interpret", "xla",
                              "fp64"))
@@ -221,16 +204,6 @@ def main(argv=None):
                 f"--levels expects an integer or 'auto', got {args.levels!r}"
             ) from None
 
-    mesh = None
-    if args.mesh is not None:
-        try:
-            b_sh, p_sh = (int(e) for e in args.mesh.lower().split("x"))
-        except ValueError:
-            raise SystemExit(
-                f"--mesh expects BxP (e.g. 2x2), got {args.mesh!r}") \
-                from None
-        mesh = (b_sh, p_sh)
-
     # one token => homogeneous path (name:N is shorthand for --n N, so the
     # report keeps the real scenario label); several tokens => mixed padded
     # ensemble, bare names inheriting --n.  ScenarioSpec.parse validates at
@@ -265,9 +238,8 @@ def main(argv=None):
         scenario=scenario_name, n=n_arg, seed=args.seed,
         ensemble=args.ensemble, t_end=args.t_end, dt=args.dt,
         stepper=args.stepper, dt_max=args.dt_max, n_levels=n_levels,
-        compaction=args.compaction, bucket_mode=args.bucket_mode,
-        block_i=args.block_i,
-        block_j=args.block_j, sources=args.sources, mesh=mesh,
+        compaction=args.compaction, block_i=args.block_i,
+        block_j=args.block_j, sources=args.sources,
         neighbor_radius=args.neighbor_radius,
         refresh_levels=args.refresh_levels, eta=args.eta,
         order=args.order, strategy=args.strategy, devices=args.devices,
@@ -289,8 +261,7 @@ def main(argv=None):
     print(f"[sim] scenario={desc} "
           f"ensemble={report['ensemble']} strategy={args.strategy} "
           f"devices={args.devices} order={args.order} "
-          + (f"mesh={mesh[0]}x{mesh[1]} " if mesh else "")
-          + f"stepper={report.get('stepper', 'fixed')} "
+          f"stepper={report.get('stepper', 'fixed')} "
           f"dtype={args.dtype}"
           + (f" sources={args.sources}" if args.sources != "full" else "")
           + (f" kernel={args.kernel}" if args.kernel else ""))

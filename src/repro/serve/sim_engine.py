@@ -91,32 +91,15 @@ class ServerConfig:
     neighbor_radius: float = 0.25
     refresh_levels: int = 2
     devices: int = 1
-    mesh: Optional[Tuple[int, int]] = None   # fused (batch, domain) grid for
-    #   block pods; product must equal devices (JSON manifests round-trip it
-    #   as a 2-list, so compare via tuple())
 
     def validate(self) -> "ServerConfig":
         if self.slots_per_pod < 1:
             raise ValueError(
                 f"slots_per_pod={self.slots_per_pod} must be >= 1")
-        if self.mesh is not None:
-            if len(self.mesh) != 2 or any(int(e) < 1 for e in self.mesh):
-                raise ValueError(
-                    f"mesh={self.mesh!r} must be two positive extents "
-                    "(B_shards, P_shards)")
-            if self.mesh[0] * self.mesh[1] != self.devices:
-                raise ValueError(
-                    f"mesh={tuple(self.mesh)} covers "
-                    f"{self.mesh[0] * self.mesh[1]} devices; devices says "
-                    f"{self.devices}")
-        # the batch axis pads to the mesh's batch extent (all of `devices`
-        # without a fused mesh)
-        batch_extent = self.mesh[0] if self.mesh is not None else self.devices
-        if batch_extent >= 1 and self.slots_per_pod % batch_extent:
+        if self.devices >= 1 and self.slots_per_pod % self.devices:
             raise ValueError(
                 f"slots_per_pod={self.slots_per_pod} must be a multiple of "
-                f"the batch extent {batch_extent} (the batch axis shards "
-                "evenly)")
+                f"devices={self.devices} (the batch axis shards evenly)")
         if self.chunk_events < 1:
             raise ValueError(
                 f"chunk_events={self.chunk_events} must be >= 1")
@@ -343,9 +326,7 @@ class Pod:
                 eta=cfg.eta, compaction=cfg.compaction,
                 block_i=cfg.block_i, block_j=cfg.block_j,
                 sources=cfg.sources, neighbor_radius=cfg.neighbor_radius,
-                refresh_levels=cfg.refresh_levels,
-                mesh=tuple(cfg.mesh) if cfg.mesh is not None else None,
-                **kw)
+                refresh_levels=cfg.refresh_levels, **kw)
         jax.block_until_ready(self.batched.pos)
         wall = time.perf_counter() - t0
         times = np.asarray(self.batched.time, np.float64)

@@ -63,14 +63,10 @@ class SimConfig:
     n_levels: Optional[int] = 8      # block hierarchy depth (None => auto:
     #   per-member from the initial Aarseth dt distribution, clamped [1, 8])
     compaction: str = "none"         # "none" | "gather" (block stepper only)
-    bucket_mode: str = "member"      # "member" (per-member capacity bucket
-    #   groups) | "shared" (batch-shared bucket baseline); gather mode only
     block_i: Optional[int] = None    # kernel tile shape override (block
     block_j: Optional[int] = None    #   stepper; None => kernel defaults)
     sources: str = "full"            # "full" | "neighbor" (Ahmad-Cohen
     #   near/far split; block stepper only — see docs/ensembles.md)
-    mesh: Optional[Tuple[int, int]] = None  # fused (batch, domain) device
-    #   grid (block stepper; product must equal devices — --mesh BxP)
     neighbor_radius: float = 0.25    # AC window radius (simulation length)
     refresh_levels: int = 2          # far-field refresh: levels below macro
     eta: float = 0.02
@@ -115,15 +111,6 @@ class SimConfig:
             raise ValueError(
                 f"compaction={self.compaction!r} only applies to the block "
                 "stepper (the lockstep modes evaluate every target)")
-        if self.bucket_mode not in ens.BUCKET_MODES:
-            raise ValueError(
-                f"bucket_mode must be one of {ens.BUCKET_MODES}; "
-                f"got {self.bucket_mode!r}")
-        if self.bucket_mode != "member" and self.compaction != "gather":
-            raise ValueError(
-                f"bucket_mode={self.bucket_mode!r} selects the capacity-"
-                "bucket dispatch of compaction='gather'; without gather "
-                "there are no buckets to share")
         if (self.block_i or self.block_j) and stepper != "block":
             raise ValueError(
                 "block_i/block_j tile overrides only reach the block "
@@ -156,33 +143,6 @@ class SimConfig:
         if self.refresh_levels < 0:
             raise ValueError(
                 f"refresh_levels={self.refresh_levels} must be >= 0")
-        if self.mesh is not None:
-            if stepper != "block":
-                raise ValueError(
-                    "mesh=(B, P) fuses batch and domain sharding of the "
-                    f"block engine; stepper={stepper!r} has no domain-"
-                    "sharded force pass to fuse")
-            if len(self.mesh) != 2 or any(int(e) < 1 for e in self.mesh):
-                raise ValueError(
-                    f"mesh={self.mesh!r} must be two positive extents "
-                    "(B_shards, P_shards)")
-            if self.mesh[0] * self.mesh[1] != self.devices:
-                raise ValueError(
-                    f"mesh={tuple(self.mesh)} covers "
-                    f"{self.mesh[0] * self.mesh[1]} devices; --devices says "
-                    f"{self.devices} (the fused grid must tile the device "
-                    "list exactly)")
-            if self.strategy != "single":
-                raise ValueError(
-                    "mesh=(B, P) supplies the domain sharding itself; "
-                    f"strategy={self.strategy!r} would shard the same axis "
-                    "twice")
-            if self.bucket_mode != "member":
-                raise ValueError(
-                    "the fused mesh engine sizes one capacity bucket per "
-                    f"(batch, domain) shard; bucket_mode="
-                    f"{self.bucket_mode!r} selects the vmapped engine's "
-                    "dispatch and would be silently ignored")
         if self.n_levels is None and stepper != "block":
             raise ValueError(
                 "n_levels=None (--levels auto) sizes the block hierarchy; "
@@ -202,11 +162,7 @@ class SimConfig:
             meta["dt_max"] = self.dt_max
             meta["n_levels"] = self.n_levels    # None until auto-resolved
             meta["compaction"] = self.compaction
-            if self.compaction == "gather":
-                meta["bucket_mode"] = self.bucket_mode
             meta["sources"] = self.sources
-            if self.mesh is not None:
-                meta["mesh"] = list(self.mesh)
             if self.sources == "neighbor":
                 meta["neighbor_radius"] = self.neighbor_radius
                 meta["refresh_levels"] = self.refresh_levels
@@ -686,11 +642,6 @@ class EnsembleRunner(Runner):
         na = jnp.asarray(n_active, jnp.int32)
         h.kw = dict(n_active=na, order=cfg.order, eps=cfg.eps, impl=impl,
                     devices=devices, dtype=cfg.dtype)
-        if cfg.mesh is not None:
-            # validated block-only, so the lockstep entry points (which
-            # take no mesh) never see the key
-            h.kw["mesh"] = tuple(int(e) for e in cfg.mesh)
-            h.kw["devices"] = _device_list(cfg)
         batched = ens.ensemble_initialize(batched, **h.kw)
         jax.block_until_ready(batched.pos)
         h.batched = batched
@@ -809,17 +760,13 @@ class EnsembleRunner(Runner):
         cfg = h.cfg
         tracer = obs_trace.get_tracer()
         reg = obs_metrics.registry()
-        # the fused mesh engine's capacity switch lives inside the shards
-        # (one shared bucket per (batch, domain) shard): there is no
-        # batch-level hit distribution to report
-        batch_hits = cfg.compaction == "gather" and cfg.mesh is None
+        batch_hits = cfg.compaction == "gather"
         t0 = time.perf_counter()
         with tracer.span("sim.engine"):
             h.batched, h.carry = ens.ensemble_run_block(
                 h.batched, t_end=cfg.t_end, n_events=cfg.diag_every,
                 dt_max=cfg.dt_max, n_levels=h.n_levels, carry=h.carry,
                 eta=cfg.eta, compaction=cfg.compaction,
-                bucket_mode=cfg.bucket_mode,
                 block_i=cfg.block_i, block_j=cfg.block_j,
                 sources=cfg.sources, neighbor_radius=cfg.neighbor_radius,
                 refresh_levels=cfg.refresh_levels, **h.kw)
@@ -853,22 +800,18 @@ class EnsembleRunner(Runner):
             # particle) is the largest active set any tick of the block
             # schedule can see, so per member and event the launch can
             # never exceed the tiles of occ[0]'s capacity bucket
-            # the full-N tile bound doesn't transfer to the fused mesh,
-            # whose launches are sized by P shard-local plans (the engine
-            # already schedules from the analytic per-shard bound there)
-            if cfg.mesh is None:
-                occ0 = np.asarray(jax.vmap(
-                    lambda lv, m: hermite.block_level_occupancy(
-                        lv, n_levels=h.n_levels, mask=m))(
-                            h.carry.levels, jnp.asarray(h.mask)))[:, 0]
-                for i in range(h.b):
-                    per_event = (int(h.plan.tiles(h.plan.bucket(
-                        int(occ0[i])))) if cfg.compaction == "gather"
-                                 else h.plan.dense_tiles)
-                    h.bound_total += ev_d[i] * per_event
-                reg.gauge("sim.tiles_occupancy_bound", unit="tiles",
-                          help="analytic bound; launched <= bound").set(
-                    h.bound_total)
+            occ0 = np.asarray(jax.vmap(
+                lambda lv, m: hermite.block_level_occupancy(
+                    lv, n_levels=h.n_levels, mask=m))(
+                        h.carry.levels, jnp.asarray(h.mask)))[:, 0]
+            for i in range(h.b):
+                per_event = (int(h.plan.tiles(h.plan.bucket(
+                    int(occ0[i])))) if cfg.compaction == "gather"
+                             else h.plan.dense_tiles)
+                h.bound_total += ev_d[i] * per_event
+            reg.gauge("sim.tiles_occupancy_bound", unit="tiles",
+                      help="analytic bound; launched <= bound").set(
+                h.bound_total)
             for i in range(h.b):
                 if ev_d[i] > 0 and h.n_active[i] > 0:
                     reg.histogram(

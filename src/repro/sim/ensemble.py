@@ -59,8 +59,7 @@ from repro.core.evaluate import (make_block_evaluator, make_evaluator,
                                  shared_cap_index)
 from repro.core.hermite import Evaluation
 from repro.core.nbody import ParticleState
-from repro.core.strategies import (STRATEGIES, make_batch_mesh,
-                                   make_fused_mesh)
+from repro.core.strategies import STRATEGIES, make_batch_mesh
 from repro.kernels import nbody_force, neighbor, ops
 from repro.obs import metrics as obs_metrics
 
@@ -181,24 +180,12 @@ def _mask_evaluator(ev, n_active):
 
 
 def _constrain(tree, mesh):
-    """Shard the leading (batch) axis of every leaf over the mesh.
-
-    On a fused 2-D ``(ensemble, dev)`` mesh (:func:`_fused_mesh`) the
-    second — particle — axis of ``(B, N, ...)`` leaves additionally shards
-    over the ``"dev"`` axis whenever it divides evenly; leaves whose second
-    axis does not (e.g. the neighbor carry's per-block window tables) keep
-    the batch-only layout, which is always correct — the constraint is a
-    layout hint, never semantics.
-    """
+    """Shard the leading (batch) axis of every leaf over the mesh."""
     if mesh is None:
         return tree
-    fused = len(mesh.axis_names) == 2
-    p = mesh.shape["dev"] if fused else 1
 
     def one(x):
         axes = [BATCH_AXIS] + [None] * (x.ndim - 1)
-        if fused and x.ndim >= 2 and x.shape[1] % p == 0:
-            axes[1] = "dev"
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P(*axes)))
 
@@ -207,11 +194,11 @@ def _constrain(tree, mesh):
 
 def _batch_map(fn, mesh, in_axes=0):
     """``vmap(fn, in_axes)`` over the batch axis; on a mesh, inside a
-    ``shard_map`` over that axis, so each device runs its own members
-    (replicated over the ``"dev"`` axis of a fused mesh).  A Pallas kernel
-    cannot be partitioned through sharding constraints.  A batch that does
-    not divide over the mesh (one bucket group of the block engine) is
-    padded by repeating its first member, and the padding is dropped."""
+    ``shard_map`` over that axis, so each device runs its own members.  A
+    Pallas kernel cannot be partitioned through sharding constraints.  A
+    batch that does not divide over the mesh (one bucket group of the block
+    engine) is padded by repeating its first member, and the padding is
+    dropped."""
     fn = jax.vmap(fn, in_axes=in_axes)
     if mesh is None:
         return fn
@@ -304,24 +291,6 @@ def _batch_mesh(devices) -> Optional[object]:
     return make_batch_mesh(devices, axis_name=BATCH_AXIS)
 
 
-def _fused_mesh(devices, mesh_shape):
-    """2-D ``(ensemble, dev)`` mesh for the fused engines (see
-    :func:`repro.core.strategies.make_fused_mesh`; validates the device
-    count against ``mesh_shape``)."""
-    devs = list(devices) if devices is not None else jax.devices()
-    return make_fused_mesh(devs, mesh_shape=tuple(int(x) for x in mesh_shape),
-                           axis_names=(BATCH_AXIS, "dev"))
-
-
-def _mesh_batch_extent(mesh) -> int:
-    """How many ways the batch axis is sharded (the `_pad_batch` multiple)."""
-    if mesh is None:
-        return 1
-    if len(mesh.axis_names) == 2:
-        return mesh.shape[BATCH_AXIS]
-    return mesh.size
-
-
 def _as_n_active(batched: ParticleState, n_active) -> jax.Array:
     """Normalize ``n_active`` to a (B,) int32 vector (default: all active)."""
     b, n = batched.pos.shape[0], batched.pos.shape[1]
@@ -364,20 +333,13 @@ def ensemble_initialize(
     impl: str = "xla",
     dtype: str = "fp32",
     devices: Optional[Sequence[jax.Device]] = None,
-    mesh: Optional[Sequence[int]] = None,
 ) -> ParticleState:
-    """Bootstrap derivatives for every ensemble member (batched t=0 pass).
-
-    ``mesh=(B_shards, P_shards)`` lays the batch out on the fused 2-D mesh
-    (see :func:`ensemble_run_block`); the bootstrap math itself is the
-    vmapped evaluator either way — constraints only steer the layout.
-    """
-    mesh_obj = _fused_mesh(devices, mesh) if mesh is not None else \
-        _batch_mesh(devices)
-    init, _ = _engine(order, eps, impl, mesh_obj, dtype)
+    """Bootstrap derivatives for every ensemble member (batched t=0 pass)."""
+    mesh = _batch_mesh(devices)
+    init, _ = _engine(order, eps, impl, mesh, dtype)
     n_active = _as_n_active(batched, n_active)
     (padded, na), b = _pad_batch((batched, n_active),
-                                 _mesh_batch_extent(mesh_obj))
+                                 mesh.size if mesh else 1)
     out = init(padded, na)
     return jax.tree_util.tree_map(lambda x: x[:b], out)
 
@@ -735,12 +697,8 @@ class BlockCarry(NamedTuple):
     nbr: Optional[NeighborCarry] = None
 
 
-#: per-member capacity-bucket dispatch modes of the block engine
-BUCKET_MODES = ("member", "shared")
-
-
 def _bucket_groups(n: int, n_active, block_i: int, block_j: int,
-                   compaction: str, bucket_mode: str) -> tuple:
+                   compaction: str) -> tuple:
     """Static pre-lowered bucket groups of a (possibly mixed) batch.
 
     Members are grouped by the ceiling bucket of their *static* ``n_active``
@@ -749,19 +707,14 @@ def _bucket_groups(n: int, n_active, block_i: int, block_j: int,
     schedule truncated at that ceiling (``ops.CapacityPlan.restrict``), so a
     quiescent small member in a mixed batch never launches — nor even
     lowers — the widest member's buckets.  Returns a tuple of
-    ``(member_indices, n_caps)`` pairs partitioning ``range(B)``; with
-    ``bucket_mode="shared"`` (or without compaction) the whole batch is one
-    group over the full schedule — exactly the original batch-shared
-    dispatch, which a homogeneous batch also reduces to in ``"member"``
-    mode (one ceiling => one group).
+    ``(member_indices, n_caps)`` pairs partitioning ``range(B)``; without
+    compaction the whole batch is one group over the full schedule, which a
+    homogeneous batch also reduces to (one ceiling => one group).
     """
-    if bucket_mode not in BUCKET_MODES:
-        raise ValueError(
-            f"bucket_mode must be one of {BUCKET_MODES}; got {bucket_mode!r}")
     na = np.asarray(n_active)
     b = na.shape[0]
     plan = ops.CapacityPlan(n, n, block_i, block_j)
-    if compaction != "gather" or bucket_mode == "shared":
+    if compaction != "gather":
         return ((tuple(range(b)), len(plan.caps)),)
     by: dict = {}
     for member, a in enumerate(na):
@@ -1164,114 +1117,6 @@ def _block_engine(order: int, eps: float, impl: str, mesh,
     return init, run
 
 
-@functools.lru_cache(maxsize=64)
-def _fused_block_engine(mesh, order: int, eps: float, impl: str,
-                        eta: float, dt_max: float, n_levels: int,
-                        compaction: str, block_i: int, block_j: int,
-                        dtype: str):
-    """Block-timestep engine over a fused 2-D ``(ensemble, dev)`` mesh: B
-    members x P domain shards in ONE shard_mapped force evaluation
-    (:func:`repro.core.strategies.make_fused_block_evaluator`).
-
-    The event schedule is the vmapped ensemble engine's, verbatim
-    (:func:`_event_pre` / :func:`_event_post`), so trajectories are
-    bit-identical to the 1-D batch-sharded run of the same members under
-    any extent-independent kernel (the Pallas grid; XLA CPU's dense
-    reduction is extent-reassociated, matching the 1-D ``mesh_sharded``
-    strategy bitwise instead).  Capacity buckets are sized **host-side**
-    (ROADMAP 5c): each member's per-shard bound is the analytic
-    ``hermite.block_level_occupancy`` of its contiguous level chunks at the
-    event tick's threshold level — no runtime gather of the activity mask
-    feeds the bucket switch, and the bound is exact for a
-    schedule-consistent carry (over-wide never under-wide, so physics is
-    bit-for-bit either way).
-    """
-    from repro.core.strategies import make_fused_block_evaluator
-
-    _count_engine_build("block_fused")
-    bdev, p = mesh.devices.shape
-    bev = make_fused_block_evaluator(
-        (bdev, p), devices=list(mesh.devices.reshape(-1)), eps=eps,
-        order=order, impl=impl, block_i=block_i, block_j=block_j,
-        compaction=compaction, dtype=dtype)
-    n_sub = 2 ** (n_levels - 1)
-    member_init = functools.partial(_event_init, eta=eta, dt_max=dt_max,
-                                    n_levels=n_levels)
-    member_pre = functools.partial(_event_pre, n_sub=n_sub)
-    member_post = functools.partial(_event_post, n_sub=n_sub, eta=eta,
-                                    dt_max=dt_max, n_levels=n_levels,
-                                    order=order)
-
-    @functools.partial(jax.jit, static_argnames=("n_events",))
-    def run(batched, carry: BlockCarry, n_active, t_end, n_events: int):
-        batched, n_active = _constrain((batched, n_active), mesh)
-        n = batched.pos.shape[1]
-        n_pad = -(-n // p) * p
-        count_dtype = jax.dtypes.canonicalize_dtype(jnp.float64)
-
-        def member_bound(lev, na, tn):
-            # host-side tile scheduling: the analytic occupancy bound of
-            # each contiguous N/P level chunk at the tick's threshold
-            # level, padding rows masked out
-            thr = hermite.tick_threshold_level(tn, n_levels=n_levels)
-            real = jnp.arange(n_pad) < na
-            lev_p = jnp.pad(lev, (0, n_pad - n))
-            return jax.vmap(
-                lambda lv, mk: hermite.block_level_occupancy(
-                    lv, n_levels=n_levels, mask=mk)[thr]
-            )(lev_p.reshape(p, -1), real.reshape(p, -1))
-
-        def body(acc, _):
-            s, c = acc
-            with jax.named_scope("event_loop.pre"):
-                live, t_next, active, h, xp, vp, ap = jax.vmap(
-                    member_pre, in_axes=(0, 0, 0, 0, 0, 0))(
-                        s, c.t_last, c.levels, c.dt_macro, n_active, t_end)
-            with jax.named_scope("compaction.bucket_index"):
-                bound = jax.vmap(member_bound)(c.levels, n_active, t_next)
-                bound = jnp.where(live[:, None], bound, 0)
-            ev, tiles = bev(xp, vp, ap, s.mass, active, bound)
-            with jax.named_scope("event_loop.post"):
-                s1, t_last, levels, dt_macro, dp, live = jax.vmap(
-                    member_post, in_axes=(0,) * 11)(
-                        s, ev, live, t_next, active, h, c.t_last, c.levels,
-                        c.dt_macro, n_active, t_end)
-            with jax.named_scope("event_loop.carry"):
-                tiles_m = jnp.sum(tiles, axis=1).astype(count_dtype)
-                c1 = BlockCarry(
-                    t_last=t_last, levels=levels, dt_macro=dt_macro,
-                    n_pairs=c.n_pairs + dp,
-                    n_events=c.n_events + live.astype(jnp.int32),
-                    n_tiles=c.n_tiles + jnp.where(live, tiles_m, 0.0),
-                    # the shared switch lives inside the shards (one
-                    # bucket per shard, not per member) — no batch-level
-                    # hit distribution to report
-                    bucket_hits=c.bucket_hits)
-                return (_constrain(s1, mesh), c1), None
-
-        with jax.named_scope("event_loop.carry"):
-            (batched, carry), _ = jax.lax.scan(body, (batched, carry), None,
-                                               length=n_events)
-        return batched, carry
-
-    @jax.jit
-    def init(batched, n_active, t_end):
-        t_last, levels, dt_macro = jax.vmap(
-            member_init, in_axes=(0, 0, 0))(batched, n_active, t_end)
-        b, n = t_last.shape
-        count_dtype = jax.dtypes.canonicalize_dtype(jnp.float64)
-        n_caps = len(ops.CapacityPlan(n, n, block_i, block_j).caps)
-        return BlockCarry(
-            t_last=t_last, levels=levels, dt_macro=dt_macro,
-            n_pairs=jnp.zeros(b, count_dtype),
-            n_events=jnp.zeros(b, jnp.int32),
-            n_tiles=jnp.zeros(b, count_dtype),
-            bucket_hits=jnp.zeros((b, n_caps), count_dtype),
-            nbr=None)
-
-    return init, run
-
-
 def ensemble_run_block(
     batched: ParticleState,
     *,
@@ -1287,14 +1132,12 @@ def ensemble_run_block(
     impl: str = "xla",
     dtype: str = "fp32",
     compaction: str = "none",
-    bucket_mode: str = "member",
     block_i: Optional[int] = None,
     block_j: Optional[int] = None,
     sources: str = "full",
     neighbor_radius: float = 0.25,
     refresh_levels: int = 2,
     devices: Optional[Sequence[jax.Device]] = None,
-    mesh: Optional[Sequence[int]] = None,
 ):
     """Advance an initialized batch by up to ``n_events`` block events each.
 
@@ -1311,14 +1154,10 @@ def ensemble_run_block(
     ``compaction="gather"`` gathers each event's active targets into a
     dense block-aligned buffer sized from a static capacity schedule and
     launches the kernels on the shrunk ``ceil(cap/BI) x N/BJ`` grid
-    (bit-for-bit the masked dense result).  ``bucket_mode`` controls how a
-    batch shares capacity buckets: ``"member"`` (default) groups members by
-    their static ``n_active`` ceiling into pre-lowered bucket groups (see
+    (bit-for-bit the masked dense result).  Members are grouped by their
+    static ``n_active`` ceiling into pre-lowered bucket groups (see
     :func:`_bucket_groups`), so a mixed batch's quiescent members stop
-    paying for its widest member's grid; ``"shared"`` is the original
-    batch-shared bucket (one group, the baseline the heterogeneous-bucket
-    regression test measures against).  Both modes are bit-for-bit
-    identical physics — only the launch schedule differs.
+    paying for its widest member's grid.
     ``block_i``/``block_j`` override the kernel tile shape (default: the
     kernel's own); the compaction win is bounded by ``N / block_i``, so
     small-N runs want a smaller ``block_i`` than the all-pairs default.
@@ -1332,22 +1171,6 @@ def ensemble_run_block(
     (:func:`spatial_sort_batched`; the convenience entry points do it) so
     index blocks are spatially tight.  ``sources="full"`` is bit-identical
     to the pre-neighbor engine.
-
-    ``mesh=(B_shards, P_shards)`` fuses batch and domain sharding over
-    ``B_shards * P_shards`` devices (the ``--mesh BxP`` CLI axis).  With
-    ``sources="full"`` the force evaluation runs through ONE shard_map over
-    the 2-D mesh (:func:`_fused_block_engine`): each device holds
-    ``B/B_shards`` members x ``N/P_shards`` target rows, capacity buckets
-    are sized host-side from the analytic ``block_level_occupancy`` bound
-    and shared per shard (``bucket_mode`` does not apply — the switch lives
-    inside the shards).  With ``sources="neighbor"`` the vmapped engine
-    keeps running and the 2-D mesh rides as a sharding *constraint* on the
-    ``(B, N)`` state leaves between events, which is what lets several
-    large-N neighbor-scheme members share one device group's memory; the
-    windowed kernels run per batch shard inside a ``shard_map``, each
-    member gathered whole along ``dev`` (a Pallas kernel cannot be
-    partitioned by sharding constraints).  ``mesh=None``
-    (default) is the 1-D batch-sharded layout, unchanged.
     """
     if n_levels < 1:
         raise ValueError(f"n_levels={n_levels} must be >= 1")
@@ -1362,9 +1185,8 @@ def ensemble_run_block(
         raise ValueError(f"refresh_levels={refresh_levels} must be >= 0")
     # an unknown compaction mode fails in make_block_evaluator (same
     # ValueError) when the engine is first built — no duplicate check here
-    mesh_obj = _fused_mesh(devices, mesh) if mesh is not None else \
-        _batch_mesh(devices)
-    bext = _mesh_batch_extent(mesh_obj)
+    mesh = _batch_mesh(devices)
+    bext = mesh.size if mesh else 1
     n_active = _as_n_active(batched, n_active)
     t_end_ = _as_t_end(batched, t_end)
     if carry is None:
@@ -1375,20 +1197,13 @@ def ensemble_run_block(
             (batched, n_active, t_end_, carry), bext)
     bi = block_i or nbody_force.DEFAULT_BLOCK_I
     bj = block_j or nbody_force.DEFAULT_BLOCK_J
-    if mesh is not None and sources == "full":
-        init, run = _fused_block_engine(
-            mesh_obj, order, eps, impl, eta, dt_max, n_levels, compaction,
-            bi, bj, dtype)
-    else:
-        # groups come from the *padded* batch (padding repeats the first
-        # run, so it lands in that run's group); n_active must be concrete
-        # here — these entry points run host-side loops anyway
-        groups = _bucket_groups(padded.pos.shape[1], na, bi, bj, compaction,
-                                bucket_mode)
-        init, run = _block_engine(
-            order, eps, impl, mesh_obj, eta, dt_max, n_levels, compaction,
-            bi, bj, groups, dtype, sources, float(neighbor_radius),
-            refresh_levels)
+    # groups come from the *padded* batch (padding repeats the first run,
+    # so it lands in that run's group); n_active must be concrete here —
+    # these entry points run host-side loops anyway
+    groups = _bucket_groups(padded.pos.shape[1], na, bi, bj, compaction)
+    init, run = _block_engine(
+        order, eps, impl, mesh, eta, dt_max, n_levels, compaction, bi, bj,
+        groups, dtype, sources, float(neighbor_radius), refresh_levels)
     if carry is None:
         carry = init(padded, na, t_end_)
     out, carry = run(padded, carry, na, t_end_, n_events)
@@ -1455,21 +1270,18 @@ def evolve_ensemble_block(
     kernel: Optional[str] = None,
     dtype: str = "fp32",
     compaction: str = "none",
-    bucket_mode: str = "member",
     block_i: Optional[int] = None,
     block_j: Optional[int] = None,
     sources: str = "full",
     neighbor_radius: float = 0.25,
     refresh_levels: int = 2,
     devices: Optional[Sequence[jax.Device]] = None,
-    mesh: Optional[Sequence[int]] = None,
     n_events: int = 256,
     max_chunks: int = 100_000,
 ):
     """One-shot block-timestep convenience: stack, initialize, evolve to
     ``t_end``.  Returns ``(batched, carry)`` (see
-    :func:`ensemble_run_block`; ``mesh=(B_shards, P_shards)`` selects the
-    fused 2-D layout).  ``sources="neighbor"`` ORB-sorts the
+    :func:`ensemble_run_block`).  ``sources="neighbor"`` ORB-sorts the
     batch (``spatial_sort_batched``) before the bootstrap so the neighbor
     windows see spatially tight index blocks; the returned batch is in
     that sorted order."""
@@ -1482,14 +1294,14 @@ def evolve_ensemble_block(
         batched = spatial_sort_batched(batched, n_active,
                                        leaf=math.gcd(bi, bj))
     kw = dict(n_active=n_active, order=order, eps=eps, impl=impl,
-              dtype=dtype, devices=devices, mesh=mesh)
+              dtype=dtype, devices=devices)
     batched = ensemble_initialize(batched, **kw)
     carry = None
     for _ in range(max_chunks):
         batched, carry = ensemble_run_block(
             batched, t_end=t_end, n_events=n_events, dt_max=dt_max,
             n_levels=n_levels, carry=carry, eta=eta, compaction=compaction,
-            bucket_mode=bucket_mode, block_i=block_i, block_j=block_j,
+            block_i=block_i, block_j=block_j,
             sources=sources, neighbor_radius=neighbor_radius,
             refresh_levels=refresh_levels, **kw)
         if float(jnp.min(batched.time)) >= t_end:

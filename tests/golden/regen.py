@@ -69,22 +69,6 @@ CASES = {
         compaction="gather", block_i=8, block_j=128,
         dt_max=1.0 / 64, n_levels=4, t_end=0.0625, eta=0.02, order=6,
         eps=1e-7),
-    # Fused (batch, dev) mesh fixture: B=2 plummer members x P=2 domain
-    # shards in ONE shard_map over 4 host devices, capacity switch sized
-    # from the host-side analytic occupancy bound.  pallas_interpret's
-    # fixed j-block sweep is launch-extent-independent, so this golden is
-    # bit-identical to the 1-D batch-sharded ensemble run AND the per-
-    # member 1-D mesh_sharded strategy run of the same recipe (the replay
-    # test in tests/test_fused_mesh.py pins all three against this file).
-    # Block sizes stay at the kernel defaults: the one-shot wrappers
-    # bootstrap with default tiles, so explicit tiles here would change
-    # the init-force summation order between the layouts' entry points.
-    "plummer_block_fused_2x2.json": dict(
-        scenario="plummer", n=64, seed=1, ensemble=2, mode="block_fused",
-        impl="pallas_interpret", devices=4, mesh=[2, 2],
-        compaction="gather",
-        dt_max=0.0625, n_levels=4, t_end=0.0625, eta=0.02, order=6,
-        eps=1e-7),
     # Ahmad-Cohen neighbor split (sources="neighbor"): near force from
     # gathered per-block windows, far field NM08-predicted between level
     # refreshes.  The fp64 oracle pins the split itself (window build, far
@@ -104,21 +88,6 @@ CASES = {
 
 def integrate(meta: dict):
     state = scenarios.make(meta["scenario"], meta["n"], seed=meta["seed"])
-    if meta.get("mode") == "block_fused":
-        states = [scenarios.make(meta["scenario"], meta["n"],
-                                 seed=meta["seed"] + i)
-                  for i in range(meta["ensemble"])]
-        batched, carry = ens.evolve_ensemble_block(
-            states, t_end=meta["t_end"], dt_max=meta["dt_max"],
-            n_levels=meta["n_levels"], eta=meta["eta"],
-            order=meta["order"], eps=meta["eps"], impl=meta["impl"],
-            compaction=meta["compaction"], mesh=tuple(meta["mesh"]),
-            devices=jax.devices()[:meta["devices"]])
-        # per-member event counts fingerprint the level schedule; per-
-        # member tiles fingerprint the host-side analytic bucket sizing
-        return (ens.stack_states(states), batched,
-                [int(e) for e in np.asarray(carry.n_events)],
-                [float(t) for t in np.asarray(carry.n_tiles)])
     if meta.get("mode") == "block_strategy":
         out, carry = ens.evolve_strategy_block(
             state, t_end=meta["t_end"], dt_max=meta["dt_max"],
@@ -170,13 +139,10 @@ def main(only: str | None = None):
         if devices > jax.device_count():
             _respawn(fname, devices)
             continue
-        state, out, n_events, *rest = integrate(meta)
+        state, out, n_events = integrate(meta)
         if meta.get("mode") == "block_strategy":
             evaluator = (f"fp32 {meta['strategy']} strategy x "
                          f"{meta['devices']} devices")
-        elif meta.get("mode") == "block_fused":
-            evaluator = (f"fp32 fused {tuple(meta['mesh'])} mesh x "
-                         f"{meta['devices']} devices ({meta['impl']})")
         else:
             evaluator = "fp64 golden (kernels.ref at x64)"
         doc = {
@@ -193,8 +159,6 @@ def main(only: str | None = None):
         }
         if n_events is not None:
             doc["n_events"] = n_events
-        if rest:
-            doc["n_tiles"] = rest[0]
         path = os.path.join(HERE, fname)
         with open(path, "w") as f:
             json.dump(doc, f, indent=1)

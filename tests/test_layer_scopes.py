@@ -140,7 +140,7 @@ def engine_text(impl, sharding=None):
     compaction, N = 1024 with 256 x 512 tiles (three capacity buckets),
     five levels, 14 events."""
     groups = ens._bucket_groups(N, np.full(1, N), BLOCK_I, BLOCK_J,
-                                "gather", "member")
+                                "gather")
     init, run = ens._block_engine(6, 1e-7, impl, None, 0.02, 1 / 64, LEVELS,
                                   "gather", BLOCK_I, BLOCK_J, groups, "fp32")
     state = jax.eval_shape(lambda: nbody.zeros_like_state(

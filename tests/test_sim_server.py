@@ -50,11 +50,13 @@ except ImportError:  # optional dependency; the exhaustive test still runs
 
 if given is not None:
     @settings(deadline=None, max_examples=200)
-    @given(n=st.integers(min_value=1, max_value=1024),
-           shape=st.sampled_from([(1024, 32, 32), (1024, 64, 64),
-                                  (512, 32, 64)]))
-    def test_packed_tiles_never_exceed_fifo_property(n, shape):
-        n_max, bi, bj = shape
+    @given(case=st.sampled_from([(1024, 32, 32), (1024, 64, 64),
+                                 (512, 32, 64)]).flatmap(
+        lambda shape: st.tuples(st.just(shape),
+                                st.integers(min_value=1,
+                                            max_value=shape[0]))))
+    def test_packed_tiles_never_exceed_fifo_property(case):
+        (n_max, bi, bj), n = case
         plan = ops.CapacityPlan(n_max, n_max, bi, bj)
         assert packed_event_tiles(plan, n) <= fifo_event_tiles(plan, n)
 

@@ -15,12 +15,11 @@ Locks the distributed-compaction contracts:
   identity under arbitrary activity masks and uneven shard occupancy.
 * **Heterogeneous buckets**: a deliberately lopsided mixed batch (deep
   binary-rich member + quiescent two-body member) launches strictly fewer
-  ``grid_tiles_total`` under per-member bucket groups than under the
-  batch-shared-bucket baseline, with bit-for-bit identical physics (energy
-  drift unchanged).
+  ``grid_tiles_total`` under per-member bucket groups than the block engine
+  run with the whole batch as one bucket group, with bit-for-bit identical
+  physics (energy drift unchanged).
 * **Plumbing**: ``CapacityPlan`` shard/restrict units, driver routing of
-  strategy block runs (``grid_tiles_per_shard`` telemetry), bucket-mode
-  validation.
+  strategy block runs (``grid_tiles_per_shard`` telemetry).
 """
 
 import os
@@ -260,8 +259,7 @@ def test_member_bucket_never_underestimates_property():
     def run(seed, b, n, block_i):
         rng = np.random.default_rng(seed)
         n_active = rng.integers(1, n + 1, size=b)
-        groups = ens._bucket_groups(n, n_active, block_i, 128,
-                                    "gather", "member")
+        groups = ens._bucket_groups(n, n_active, block_i, 128, "gather")
         # groups partition the batch
         members = sorted(m for ms, _ in groups for m in ms)
         assert members == list(range(b))
@@ -280,20 +278,18 @@ def test_member_bucket_never_underestimates_property():
 
 
 def test_bucket_groups_modes():
-    """Homogeneous batches collapse to one full-schedule group in both
-    modes; 'shared' always returns the batch-shared baseline."""
+    """Under gather a homogeneous batch collapses to one full-schedule
+    group and a mixed batch splits by its members' ceiling buckets; without
+    gather the whole batch is one group over the full schedule."""
     caps = ops.capacity_buckets(256, 32)
-    homo = ens._bucket_groups(256, [256, 256, 256], 32, 256,
-                              "gather", "member")
+    homo = ens._bucket_groups(256, [256, 256, 256], 32, 256, "gather")
     assert homo == (((0, 1, 2), len(caps)),)
-    assert ens._bucket_groups(256, [64, 256], 32, 256, "gather", "shared") \
+    small = len(ops.CapacityPlan(256, 256, 32, 256).restrict(64).caps)
+    assert small < len(caps)
+    assert ens._bucket_groups(256, [64, 256], 32, 256, "gather") \
+        == (((0,), small), ((1,), len(caps)))
+    assert ens._bucket_groups(256, [64, 256], 32, 256, "none") \
         == (((0, 1), len(caps)),)
-    mixed = ens._bucket_groups(256, [64, 256], 32, 256, "gather", "member")
-    assert len(mixed) == 2
-    assert ens._bucket_groups(256, [64, 256], 32, 256, "none", "member") \
-        == (((0, 1), len(caps)),)
-    with pytest.raises(ValueError, match="bucket_mode"):
-        ens._bucket_groups(256, [256], 32, 256, "gather", "widest")
 
 
 def test_capacity_plan_shard_restrict_units():
@@ -326,22 +322,43 @@ def test_capacity_plan_shard_restrict_units():
 # --------------------------------------------------------------------------
 # heterogeneous buckets: lopsided mixed batch
 # --------------------------------------------------------------------------
+def _one_group_block(batched, n_active, *, t_end, dt_max, n_levels, impl,
+                     block_i, block_j, n_events=256):
+    """Run the block engine with the whole batch as ONE bucket group over
+    the full capacity schedule (the batch-shared dispatch), chunk by chunk
+    to ``t_end`` as :func:`ens.evolve_ensemble_block` does; returns the
+    initialized batch too, so callers can measure energy drift from it."""
+    b, n = ens.batch_size(batched), batched.pos.shape[1]
+    groups = ((tuple(range(b)),
+               len(ops.CapacityPlan(n, n, block_i, block_j).caps)),)
+    init, run = ens._block_engine(6, 1e-7, impl, None, 0.02, dt_max,
+                                  n_levels, "gather", block_i, block_j,
+                                  groups, "fp32")
+    na = jnp.asarray(n_active, jnp.int32)
+    t = ens._as_t_end(batched, t_end)
+    start = ens.ensemble_initialize(batched, n_active=na, impl=impl)
+    out, carry = start, init(start, na, t)
+    for _ in range(1000):
+        out, carry = run(out, carry, na, t, n_events)
+        if float(jnp.min(out.time)) >= t_end:
+            break
+    return start, out, carry
+
+
 def test_lopsided_mixed_batch_member_buckets_beat_shared():
     """One deep-hierarchy member (binary-rich Plummer) + one quiescent
     member (two-body, n_active=2 inside a 24-row pad): per-member bucket
-    groups launch strictly fewer total tiles than the batch-shared-bucket
-    baseline, at bit-for-bit identical physics (same trajectory, same
-    measured pairs, same energy drift)."""
+    groups launch strictly fewer total tiles than the one-group
+    batch-shared schedule, at bit-for-bit identical physics (same
+    trajectory, same measured pairs, same energy drift)."""
     specs = [scenarios.Scenario(name="binary_plummer", n=24, seed=1),
              scenarios.Scenario(name="two_body", n=2, seed=0)]
     batched, n_active = scenarios.build_padded(specs, n_max=24)
     kw = dict(t_end=0.0625, dt_max=1 / 64, n_levels=4, impl="xla",
-              compaction="gather", block_i=8, block_j=128,
-              n_active=n_active)
-    shared, cs = ens.evolve_ensemble_block(batched, bucket_mode="shared",
-                                           **kw)
-    member, cm = ens.evolve_ensemble_block(batched, bucket_mode="member",
-                                           **kw)
+              block_i=8, block_j=128)
+    _, shared, cs = _one_group_block(batched, n_active, **kw)
+    member, cm = ens.evolve_ensemble_block(batched, compaction="gather",
+                                           n_active=n_active, **kw)
     # launch economics: strictly fewer tiles, and the quiescent member is
     # the one that got cheaper
     assert float(np.sum(np.asarray(cm.n_tiles))) \
@@ -362,22 +379,26 @@ def test_lopsided_mixed_batch_member_buckets_beat_shared():
 
 
 def test_lopsided_mixed_driver_reports_fewer_tiles(tmp_path):
-    """The same lopsided comparison end-to-end through the driver: telemetry
-    ``grid_tiles_total`` drops under per-member buckets while the reported
-    per-run energy drift is unchanged."""
-    base = dict(mix=(("binary_plummer", 24), ("two_body", 2)), seed=0,
-                t_end=0.03125, stepper="block", dt_max=1 / 64, n_levels=4,
-                compaction="gather", block_i=8, block_j=128, impl="xla",
-                diag_every=16)
-    r_shared = driver.run(driver.SimConfig(bucket_mode="shared", **base,
-                                           out=str(tmp_path / "s.json")))
-    r_member = driver.run(driver.SimConfig(bucket_mode="member", **base,
-                                           out=str(tmp_path / "m.json")))
-    assert r_member["grid_tiles_total"] < r_shared["grid_tiles_total"]
-    assert r_member["bucket_mode"] == "member"
-    assert [r["de_rel"] for r in r_member["runs"]] \
-        == [r["de_rel"] for r in r_shared["runs"]]
-    assert r_member["force_evals_total"] == r_shared["force_evals_total"]
+    """The same lopsided comparison end-to-end through the driver: its
+    telemetry ``grid_tiles_total`` is below the one-group engine run's,
+    while the measured pairs and each member's energy drift are equal."""
+    mix = (("binary_plummer", 24), ("two_body", 2))
+    kw = dict(t_end=0.03125, dt_max=1 / 64, n_levels=4, block_i=8,
+              block_j=128, impl="xla")
+    report = driver.run(driver.SimConfig(
+        mix=mix, seed=0, stepper="block", compaction="gather", diag_every=16,
+        out=str(tmp_path / "m.json"), **kw))
+    batched, n_active = scenarios.build_padded(
+        scenarios.make_mix(mix, seed=0))
+    start, shared, cs = _one_group_block(batched, n_active, n_events=16,
+                                         **kw)
+    assert report["grid_tiles_total"] < float(np.sum(np.asarray(cs.n_tiles)))
+    assert report["force_evals_total"] \
+        == sum(float(p) for p in np.asarray(cs.n_pairs))
+    e0 = np.asarray(ens.batched_total_energy(start), np.float64)
+    e1 = np.asarray(ens.batched_total_energy(shared), np.float64)
+    assert [r["de_rel"] for r in report["runs"]] \
+        == [float(d) for d in np.abs((e1 - e0) / e0)]
 
 
 # --------------------------------------------------------------------------
@@ -401,13 +422,3 @@ def test_driver_block_strategy_reports_per_shard_tiles(tmp_path):
     plan = ops.CapacityPlan(24, 24, 8, 128)
     assert report["grid_tiles_total"] < plan.dense_tiles * report["steps"]
     assert report["steps"] > 0 and report["force_evals_total"] > 0
-
-
-def test_driver_bucket_mode_validation():
-    with pytest.raises(ValueError, match="bucket_mode"):
-        driver.SimConfig(dt=0.01, bucket_mode="widest").resolved_stepper()
-    with pytest.raises(ValueError, match="no buckets to share"):
-        driver.SimConfig(stepper="block",
-                         bucket_mode="shared").resolved_stepper()
-    # member mode is the inert default everywhere
-    driver.SimConfig(dt=0.01).resolved_stepper()

@@ -115,17 +115,13 @@ def test_strategy_evaluators_compile_on_four_chips(topo, no_persistent_cache,
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("engine", ["init/batch", "init/fused",
-                                    "adaptive/batch"])
+@pytest.mark.parametrize("engine", ["init/batch", "adaptive/batch"])
 def test_batch_sharded_engines_compile_on_four_chips(
         topo, no_persistent_cache, engine):
-    """A batch sharded over four chips, on the 1-D batch mesh or the fused
-    (2, 2) mesh: the t = 0 pass and the adaptive lockstep step run the
-    vmapped kernel per shard."""
-    devices = list(topo.devices)
-    kind, layout = engine.split("/")
-    mesh = (ens._batch_mesh(devices) if layout == "batch" else
-            ens._fused_mesh(devices, (2, 2)))
+    """A batch sharded over four chips: the t = 0 pass and the adaptive
+    lockstep step run the vmapped kernel per shard."""
+    kind = engine.split("/")[0]
+    mesh = ens._batch_mesh(list(topo.devices))
     rep = NamedSharding(mesh, P())
     b = 4
     batched = jax.tree_util.tree_map(
@@ -157,7 +153,7 @@ def test_block_engine_gather_compiles(topo, no_persistent_cache, layout):
     else:
         mesh, b = ens._batch_mesh(topo.devices), 4
         sharding = NamedSharding(mesh, P())
-    groups = ens._bucket_groups(n, np.full(b, n), bi, bj, "gather", "member")
+    groups = ens._bucket_groups(n, np.full(b, n), bi, bj, "gather")
     init, run = ens._block_engine(6, 1e-7, "pallas", mesh, 0.02, 0.0625, 8,
                                   "gather", bi, bj, groups, "fp32")
     batched = jax.tree_util.tree_map(
